@@ -29,9 +29,10 @@ Saturation is reported twice:
     brackets the knee on one CPU core; extrapolation is labelled as such).
 
 Shard scaling (``shard_scaling``): the same 10k-job force-refit tick is
-re-run in SUBPROCESSES with ``XLA_FLAGS=--xla_force_host_platform_
-device_count=k`` (the flag must be set before jax initializes, so cells
-cannot run in-process) and ``SurveillanceEngine(shards=k)``. Every cell
+re-run with ``SurveillanceEngine(shards=k)``. On a CPU host each cell is a
+SUBPROCESS with ``XLA_FLAGS=--xla_force_host_platform_device_count=k``
+(the flag must be set before jax initializes); on an accelerator the cells
+run in-process over the first k chips. Every cell
 also emits a digest of its end-to-end decide output, so cross-shard
 bit-parity is checked on the exact benchmark workload, not just in unit
 tests. On a multi-core host the 2-device cell must beat the 1-device cell;
@@ -281,9 +282,9 @@ def shard_cell(n: int, shards: int, reps: int = 3, *, warm: int = 4,
     """One shard-scaling measurement IN THIS PROCESS: a ``shards``-way
     engine (1 = the single-device reference path) timing the force-refit
     decide tick over a deterministic ``n``-job fleet, plus the end-to-end
-    decide digest for cross-shard parity. Callers must have set the device
-    count (XLA_FLAGS) before jax initialized — use ``shard_scaling`` for
-    the subprocess plumbing."""
+    decide digest for cross-shard parity. On a CPU host, callers must have
+    set the device count (XLA_FLAGS) before jax initialized — use
+    ``shard_scaling`` for the subprocess plumbing."""
     import jax
     nb = make_training_nb()
     fleet, replay = _make_fleet(n, warm + reps, seed=0, load=load)
@@ -311,11 +312,16 @@ def shard_cell(n: int, shards: int, reps: int = 3, *, warm: int = 4,
 
 def shard_scaling(n: int = 10_000, shard_counts: Sequence[int] = (1, 2),
                   reps: int = 3, load: str = "table3") -> List[Dict]:
-    """Run one ``shard_cell`` per shard count, each in a fresh SUBPROCESS
-    with ``XLA_FLAGS=--xla_force_host_platform_device_count=k`` (the flag
-    only takes effect before jax initializes, and the parent process must
-    keep its single real device so co-resident timing gates stay
-    undisturbed). Returns the cells in ``shard_counts`` order."""
+    """Run one ``shard_cell`` per shard count. On an accelerator the cells
+    run in this process over ``jax.devices()[:k]``: the process holds the
+    chips, so a child could not reach them. On a CPU host each cell runs in
+    a fresh SUBPROCESS with ``XLA_FLAGS=--xla_force_host_platform_device_
+    count=k`` (the flag only takes effect before jax initializes, and the
+    parent process must keep its single real device so co-resident timing
+    gates stay undisturbed). Returns the cells in ``shard_counts`` order."""
+    import jax
+    if jax.default_backend() != "cpu":
+        return [shard_cell(n, k, reps, load=load) for k in shard_counts]
     cells = []
     for k in shard_counts:
         env = dict(os.environ)

@@ -11,8 +11,9 @@ detailed rows to experiments/bench/<name>.json.
     jobs, that extrapolated saturation reaches >= 10,000 jobs, that the
     MEASURED saturation knee of the full-refit tick (interpolated between
     two measured bracketing sizes, never extrapolated) sits at >= 10,000
-    jobs, and that 1-vs-2-virtual-device shard cells (subprocesses, so
-    XLA_FLAGS lands before jax init) produce bit-identical decide digests
+    jobs, and that 1-vs-2-device shard cells (virtual CPU devices in
+    subprocesses, so XLA_FLAGS lands before jax init; the first chips,
+    in-process, on an accelerator) produce bit-identical decide digests
     — with the 2-device cell additionally >= 1.5x faster when the host
     actually has >= 2 CPU cores (on a single-core host that speedup is
     physically unattainable, so the gate records the measured ratio and
@@ -119,6 +120,8 @@ def quick() -> None:
     measured full-refit saturation knee, and 1-vs-2-device shard parity."""
     import os
 
+    import jax
+
     from benchmarks import fig10_scalability
     summary, rows = fig10_scalability.run(
         sizes=[5, 100, 1000, 10_000, 25_000], reps=3, steady_steps=16)
@@ -142,19 +145,22 @@ def quick() -> None:
     measured_knee_ok = bool(knee["knee_measured"]
                             and knee["knee_jobs"] >= 10_000)
 
-    # shard scaling: 1-vs-2 virtual devices on the 10k-job force-refit
-    # tick, in subprocesses (XLA_FLAGS must precede jax init; the parent
-    # keeps its single real device so co-resident timing gates hold)
-    cells = fig10_scalability.shard_scaling(n=10_000, shard_counts=(1, 2),
+    # shard scaling: 1-vs-2 devices on the 10k-job force-refit tick —
+    # virtual CPU devices in subprocesses (XLA_FLAGS must precede jax
+    # init), or the first chips of an accelerator host in-process
+    cpu = jax.default_backend() == "cpu"
+    counts = [k for k in (1, 2) if cpu or k <= jax.device_count()]
+    cells = fig10_scalability.shard_scaling(n=10_000, shard_counts=counts,
                                             reps=2)
     shard_parity = len({c["digest"] for c in cells}) == 1
     speedup_2dev = (cells[0]["tick_full_s"]
-                    / max(cells[1]["tick_full_s"], 1e-9))
-    multicore = (os.cpu_count() or 1) >= 2
-    # on a single-core host a 2-device speedup is physically unattainable
-    # (shard_map adds partitioning copies with no parallelism to pay for
-    # them) — enforce bit-parity and RECORD the measured ratio instead of
-    # gating on a number the machine cannot produce
+                    / max(cells[-1]["tick_full_s"], 1e-9))
+    # the speedup gate is a CPU-host gate: on a single-core host a 2-device
+    # speedup is physically unattainable (shard_map adds partitioning
+    # copies with no parallelism to pay for them), and chip speed is
+    # judged on the chip — enforce bit-parity and RECORD the measured
+    # ratio instead of gating on a number the machine cannot produce
+    multicore = cpu and (os.cpu_count() or 1) >= 2
     shard_speedup_ok = (speedup_2dev >= 1.5) if multicore else True
 
     payload = {

@@ -17,6 +17,7 @@ classified in one batched call.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Dict, Optional, Tuple
 
@@ -71,16 +72,39 @@ def _nb_predict(edges, ll, prior, x):
     return jnp.argmax(lp, axis=-1).astype(jnp.int32), post
 
 
-@jax.jit
-def _nb_predict_lm(edges, ll, prior, x):
+#: samples classified per step of the blocked LM classify. The (rows, C, F)
+#: likelihood gather pads its small minor dimensions to the TPU's (8, 128)
+#: tile, about 3 KB of HBM per sample, so one unblocked call over a 16k-job
+#: x 512-sample window needs ~26 GB; a block bounds it near 1 GB.
+CLASSIFY_BLOCK = 1 << 18
+
+
+@functools.partial(jax.jit, static_argnames=("block",))
+def _nb_predict_lm(edges, ll, prior, x, *, block: int):
     """LM/NLM signal only: same argmax as ``_nb_predict`` (bit-identical
     class decisions) but skips the softmax posterior — the decide-plane
     tick only consumes the binary suitability series, and this is also the
     shard_map body of the sharded classify (``core/shard.py``): no
-    cross-row reduction anywhere, so row-partitioning is exact."""
+    cross-row reduction anywhere, so row-partitioning is exact.
+
+    Samples are classified ``block`` at a time (a sequential
+    ``lax.map`` over zero-padded row blocks), so device memory is bounded
+    by the block, not the fleet; each block runs the same per-sample ops,
+    so the series is bit-identical to one call over all rows."""
     lead = x.shape[:-1]
-    lp = _nb_logprob(edges, ll, prior, x.reshape(-1, x.shape[-1]))
-    cls = jnp.argmax(lp, axis=-1)
+    rows = x.reshape(-1, x.shape[-1])
+    n = rows.shape[0]
+
+    def classify(r):
+        return jnp.argmax(_nb_logprob(edges, ll, prior, r), axis=-1)
+
+    if n <= block:
+        cls = classify(rows)
+    else:
+        n_p = -(-n // block) * block
+        rows = jnp.pad(rows, ((0, n_p - n), (0, 0)))
+        cls = jax.lax.map(classify, rows.reshape(
+            n_p // block, block, -1)).reshape(-1)[:n]
     lm = jnp.asarray(LM_SUITABLE, jnp.int8)[
         jnp.clip(cls, 0, len(LM_SUITABLE) - 1)]
     return lm.reshape(lead)
@@ -146,7 +170,8 @@ def classify_lm_batch(nb: NaiveBayes, windows: np.ndarray) -> np.ndarray:
     """
     return np.asarray(_nb_predict_lm(nb.bin_edges, nb.log_likelihood,
                                      nb.log_prior,
-                                     jnp.asarray(windows, jnp.float32)))
+                                     jnp.asarray(windows, jnp.float32),
+                                     block=CLASSIFY_BLOCK))
 
 
 def primary_secondary(classes: np.ndarray) -> Tuple[int, Optional[int]]:

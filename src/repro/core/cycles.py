@@ -23,6 +23,7 @@ surveillance ticks beyond ~100 jobs (see ``core/surveillance.py``).
 """
 from __future__ import annotations
 
+import warnings
 from dataclasses import dataclass, field
 from typing import List, Optional, Tuple
 
@@ -51,6 +52,15 @@ def _resolve_kernel(use_kernel: Optional[bool]) -> bool:
     return kops.has_accelerator() if use_kernel is None else use_kernel
 
 
+def _warn_host_fallback(stage: str, n: int) -> None:
+    """The kernel path was asked for but this window length is off its
+    tiling, so ``stage`` runs on the host. Python's default warning filter
+    reports each stage and window length once per call site."""
+    warnings.warn(f"{stage}: window length {n} is off the kernel tiling; "
+                  "computing it with numpy on the host", RuntimeWarning,
+                  stacklevel=3)
+
+
 def _spectra(X: np.ndarray, use_kernel: Optional[bool],
              mesh=None) -> np.ndarray:
     """(J, n) f32 -> (J, n//2+1) one-sided power of the mean-removed rows.
@@ -60,8 +70,10 @@ def _spectra(X: np.ndarray, use_kernel: Optional[bool],
     are already independent and host-resident.
     """
     n = X.shape[1]
-    if _resolve_kernel(use_kernel) and kops.dft_supported(n):
-        return np.asarray(kops.power_spectrum(X, center=True, mesh=mesh))
+    if _resolve_kernel(use_kernel):
+        if kops.dft_supported(n):
+            return np.asarray(kops.power_spectrum(X, center=True, mesh=mesh))
+        _warn_host_fallback("power spectrum", n)
     F = np.fft.rfft(X - X.mean(axis=1, keepdims=True), axis=1)
     return (F.real ** 2 + F.imag ** 2).astype(np.float32)
 
@@ -112,13 +124,18 @@ def _peak_pick(P: np.ndarray, n: int, min_period: int, max_period: int,
         # noise (mass hits the 1e-12 floor relative to raw power), the
         # "peak share" is 1.0-of-nothing — report confidence 0 so gates on
         # confidence fall back instead of trusting pure noise.
+        # Nor is there a peak to find: the matmul-DFT leaves rounding
+        # residue where pocketfft returns exact zeros, and a "period" fit
+        # to that residue would differ by backend.
         degen = mass <= _DEGENERATE_MASS_FRAC * np.asarray(total_power)
         conf = np.where(degen, 0.0, conf)
+        found = found & ~degen
     return k_star, conf, found
 
 
 def _refine_period_batch(X: np.ndarray, p0: np.ndarray, min_period: int,
-                         max_period: int, mesh=None) -> np.ndarray:
+                         max_period: int, mesh=None,
+                         use_kernel: Optional[bool] = None) -> np.ndarray:
     """Sharpen FFT bin estimates with a local autocorrelation search, for
     the whole fleet at once.
 
@@ -140,7 +157,11 @@ def _refine_period_batch(X: np.ndarray, p0: np.ndarray, min_period: int,
     ok = hi >= lo
     if not ok.any():
         return p0.copy()
-    if kops.has_accelerator() and n <= 2048:
+    kernel = _resolve_kernel(use_kernel)
+    if kernel and not kops.autocorr_supported(n):
+        _warn_host_fallback("period refinement", n)
+        kernel = False
+    if kernel:
         # Pallas kernel (TPU or GPU row of the dispatch table): fleet x
         # shared candidate-lag grid in one call, optionally row-sharded
         import jax.numpy as jnp
@@ -162,11 +183,11 @@ def _refine_period_batch(X: np.ndarray, p0: np.ndarray, min_period: int,
 
 
 def _refine_period(x: np.ndarray, p0: int, min_period: int,
-                   max_period: int) -> int:
+                   max_period: int, use_kernel: Optional[bool] = None) -> int:
     """Scalar view of ``_refine_period_batch`` (kept for API compat)."""
     return int(_refine_period_batch(np.asarray(x, np.float64)[None],
                                     np.asarray([p0]), min_period,
-                                    max_period)[0])
+                                    max_period, use_kernel=use_kernel)[0])
 
 
 def cycle_length(series: np.ndarray, *, min_period: int = 2,
@@ -191,7 +212,7 @@ def cycle_length(series: np.ndarray, *, min_period: int = 2,
         return 0, 0.0
     p0 = int(round(n / k_star[0]))
     return _refine_period(np.asarray(series, np.float64), p0,
-                          min_period, max_p), float(conf[0])
+                          min_period, max_p, use_kernel), float(conf[0])
 
 
 def decompose(classes: np.ndarray, period: int
@@ -244,7 +265,7 @@ def fit_cycle_batch(classes_batch: np.ndarray, *, min_period: int = 2,
     if found.any():
         refined = _refine_period_batch(X[found].astype(np.float64),
                                        p0[found], min_period, max_p,
-                                       mesh=mesh)
+                                       mesh=mesh, use_kernel=use_kernel)
         periods = periods.copy()
         periods[found] = refined
     out: List[CycleModel] = []

@@ -46,37 +46,40 @@ class PrecopyConfig:
 # ---------------------------------------------------------------------------
 # flat block view of a pytree
 # ---------------------------------------------------------------------------
-def _flatten(state) -> List[jnp.ndarray]:
-    return [l.reshape(-1) for l in jax.tree.leaves(state)]
+def _blocks(leaf: jnp.ndarray, nb: int, block: int) -> jnp.ndarray:
+    """(any shape) leaf -> (nb, block) zero-padded view of its flat data.
+    Traced inside the jitted scan/merge, so no flattened copy of the whole
+    state is ever materialized at once: that copy would double the state's
+    HBM footprint, beyond one chip for a 4.6 GB replica."""
+    flat = leaf.reshape(-1)
+    return jnp.pad(flat, (0, nb * block - flat.shape[0])).reshape(nb, block)
 
 
 @partial(jax.jit, static_argnums=(2,))
 def _leaf_dirty(new: jnp.ndarray, old: jnp.ndarray, block: int) -> jnp.ndarray:
-    """(n,) leaf pair -> (nb,) bool dirty mask."""
-    nb = -(-new.shape[0] // block)
-    pad = nb * block - new.shape[0]
-    n2 = jnp.pad(new, (0, pad)).reshape(nb, block)
-    o2 = jnp.pad(old, (0, pad)).reshape(nb, block)
-    return kops.dirty_blocks(n2, o2)
+    """Leaf pair (same shape) -> (nb,) bool dirty mask over its flat blocks."""
+    nb = -(-new.size // block)
+    return kops.dirty_blocks(_blocks(new, nb, block),
+                             _blocks(old.astype(new.dtype), nb, block))
 
 
-@partial(jax.jit, static_argnums=(3,))
+@partial(jax.jit, static_argnums=(3,), donate_argnums=(1,))
 def _leaf_merge(new: jnp.ndarray, old: jnp.ndarray, dirty: jnp.ndarray,
                 block: int) -> jnp.ndarray:
-    """Copy dirty blocks of ``new`` over ``old`` (the 'network transfer')."""
+    """Copy dirty blocks of ``new`` over ``old`` (the 'network transfer').
+    ``old``'s buffer is donated to the result, so a round holds one shadow
+    copy of the state, not two."""
     nb = dirty.shape[0]
-    pad = nb * block - new.shape[0]
-    n2 = jnp.pad(new, (0, pad)).reshape(nb, block)
-    o2 = jnp.pad(old, (0, pad)).reshape(nb, block)
-    out = jnp.where(dirty[:, None], n2, o2)
-    return out.reshape(-1)[: new.shape[0]]
+    out = jnp.where(dirty[:, None], _blocks(new, nb, block),
+                    _blocks(old.astype(new.dtype), nb, block))
+    return out.reshape(-1)[: new.size].reshape(old.shape).astype(old.dtype)
 
 
 def dirty_scan(live, shadow, block: int) -> Tuple[List[jnp.ndarray], int, int]:
     """Per-leaf dirty masks + (dirty_blocks, dirty_bytes) totals."""
     masks, n_dirty, n_bytes = [], 0, 0
-    for new, old in zip(_flatten(live), _flatten(shadow)):
-        m = _leaf_dirty(new, old.astype(new.dtype), block)
+    for new, old in zip(jax.tree.leaves(live), jax.tree.leaves(shadow)):
+        m = _leaf_dirty(new, old, block)
         masks.append(m)
         d = int(jnp.sum(m))
         n_dirty += d
@@ -85,8 +88,8 @@ def dirty_scan(live, shadow, block: int) -> Tuple[List[jnp.ndarray], int, int]:
 
 
 def merge_dirty(live, shadow, masks: List[jnp.ndarray], block: int):
-    flat_live = _flatten(live)
-    flat_shadow = _flatten(shadow)
+    """Shadow with every dirty block of ``live`` copied over it. Consumes
+    ``shadow``: its leaves are donated to the result."""
 
     def align(n, o):
         """The cross-placement transfer: move live data onto the destination
@@ -95,13 +98,10 @@ def merge_dirty(live, shadow, masks: List[jnp.ndarray], block: int):
             n = jax.device_put(n, o.sharding)
         return n
 
-    merged = [_leaf_merge(align(n, o), o.astype(n.dtype), m, block)
-              for n, o, m in zip(flat_live, flat_shadow, masks)]
     leaves = jax.tree.leaves(shadow)
-    treedef = jax.tree.structure(shadow)
-    new_leaves = [m.reshape(l.shape).astype(l.dtype)
-                  for m, l in zip(merged, leaves)]
-    return jax.tree.unflatten(treedef, new_leaves)
+    merged = [_leaf_merge(align(n, o), o, m, block)
+              for n, o, m in zip(jax.tree.leaves(live), leaves, masks)]
+    return jax.tree.unflatten(jax.tree.structure(shadow), merged)
 
 
 def total_bytes(state) -> int:

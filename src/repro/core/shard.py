@@ -29,6 +29,7 @@ follows the same rows-to-multiple-of-mesh rule as ``_pad_rows`` here.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -37,7 +38,6 @@ import numpy as np
 
 from repro.core import characterize
 from repro.core import postpone as pp
-from repro.kernels import backend as kb
 
 
 def device_count() -> int:
@@ -57,10 +57,14 @@ def decide_mesh(shards: Optional[int] = None):
         return None
     devs = jax.devices()
     if shards > len(devs):
-        raise ValueError(
-            f"requested {shards} shards but only {len(devs)} devices are "
-            "visible; set XLA_FLAGS=--xla_force_host_platform_device_count="
-            f"{shards} (before jax initializes) to fake them on CPU")
+        if devs[0].platform == "cpu":
+            hint = ("set XLA_FLAGS=--xla_force_host_platform_device_count="
+                    f"{shards} before jax initializes to fake them on CPU")
+        else:
+            hint = (f"this {devs[0].platform} host has {len(devs)}; ask for "
+                    "at most that many shards")
+        raise ValueError(f"requested {shards} shards but only {len(devs)} "
+                         f"devices are visible: {hint}")
     from jax.sharding import Mesh
     return Mesh(np.asarray(devs[:shards]), ("shard",))
 
@@ -88,9 +92,10 @@ def classify_lm(nb: characterize.NaiveBayes, windows, mesh=None) -> np.ndarray:
     axis = mesh.axis_names[0]
     x, J = _pad_rows(jnp.asarray(windows, jnp.float32),
                      int(mesh.devices.size))
-    fn = kb.shard_map_compat(
-        characterize._nb_predict_lm, mesh,
-        in_specs=(P(), P(), P(), P(axis)), out_specs=P(axis))
+    body = functools.partial(characterize._nb_predict_lm,
+                             block=characterize.CLASSIFY_BLOCK)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), P(), P(), P(axis)),
+                       out_specs=P(axis), check_vma=False)
     return np.asarray(fn(nb.bin_edges, nb.log_likelihood, nb.log_prior,
                          x))[:J]
 
@@ -113,8 +118,7 @@ def postpone_rows(profiles, periods, m_now, mesh=None) -> jnp.ndarray:
     prof, J = _pad_rows(jnp.asarray(profiles), n)
     per, _ = _pad_rows(jnp.asarray(periods), n)
     m, _ = _pad_rows(m_now, n)
-    out = kb.shard_map_compat(
-        pp.postpone_batch_jit, mesh,
-        in_specs=(P(axis), P(axis), P(axis)),
-        out_specs=P(axis))(prof, per, m)
+    out = jax.shard_map(pp.postpone_batch_jit, mesh=mesh,
+                        in_specs=(P(axis), P(axis), P(axis)),
+                        out_specs=P(axis), check_vma=False)(prof, per, m)
     return out[:J]

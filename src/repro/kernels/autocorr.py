@@ -8,13 +8,16 @@ fleet scores one shared grid of candidate lags in a single Pallas call:
 
     R[j, l] = sum_t x[j, t] * x[j, t + lag_l]        (t + lag_l < N)
 
-Grid: (job_tiles, lag_tiles). Each kernel instance keeps its block's full
-rows resident in VMEM (bt x N f32, <= 64 KB at N=2048), reads a tile of
-candidate lags from SMEM, and walks them with a fori_loop of dynamic-slice
-multiplies on the zero-extended rows (the zero tail implements the
-``t + lag < N`` mask for free). The products are VPU work — no MXU — but one
-kernel launch replaces J Python-dispatched dot-product loops, and rows are
-streamed once per lag *tile* instead of once per lag.
+Grid: (job_tiles, lag_tiles). The candidate lags are scalar-prefetched into
+SMEM whole. Each kernel instance keeps its block's full rows resident in
+VMEM (bt x N f32, <= 256 KB at N=2048) and walks its tile of lags with a
+fori_loop: the shifted row ``x[:, t + lag]`` is a lane rotation
+(``pltpu.roll`` by ``N - lag``) whose wrapped-around tail is masked off by
+``t < N - lag``, and the row sum lands in the lag's column of a (bt, LT)
+accumulator through a lane select — every access stays on the (8, 128)
+tiling. The products are VPU work — no MXU — but one kernel launch
+replaces J Python-dispatched dot-product loops, and rows are streamed once
+per lag *tile* instead of once per lag.
 
 Callers (``cycles._refine_period_batch``) pick each job's argmax over its
 own valid lag window; invalid/padding lags are masked host-side.
@@ -31,23 +34,27 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import backend as kb
 
-B_TILE = 8
-L_TILE = 8
+B_TILE = 32
+L_TILE = 128
 MAX_N = 2048
 
 
-def _kernel(x_ref, lags_ref, out_ref):
+def _kernel(lags_ref, x_ref, out_ref):
     x = x_ref[...]                                         # (bt, N)
-    xp = jnp.concatenate([x, jnp.zeros_like(x)], axis=1)   # zero tail = mask
+    n = x.shape[1]
+    lt = out_ref.shape[1]
+    t = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1)
+    base = pl.program_id(1) * lt
 
     def body(l, acc):
-        p = jnp.clip(lags_ref[l], 0, x.shape[1])
-        sh = jax.lax.dynamic_slice(xp, (0, p), x.shape)    # x[:, p:], padded
-        return acc.at[:, l].set(jnp.sum(x * sh, axis=1))
+        p = jnp.clip(lags_ref[base + l], 0, n)
+        sh = pltpu.roll(x, (n - p) % n, 1)                 # sh[:, t] = x[:, t+p]
+        s = jnp.sum(jnp.where(t < n - p, x * sh, 0.0), axis=1, keepdims=True)
+        return jnp.where(col == l, s, acc)
 
     out_ref[...] = jax.lax.fori_loop(
-        0, lags_ref.shape[0], body,
-        jnp.zeros(out_ref.shape, jnp.float32))
+        0, lt, body, jnp.zeros(out_ref.shape, jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -57,7 +64,9 @@ def _autocorr_score(x: jnp.ndarray, lags: jnp.ndarray, *,
     L = lags.shape[0]
     bt = min(B_TILE, J)
     J_p = -(-J // bt) * bt
-    L_p = -(-L // L_TILE) * L_TILE
+    # one lag tile of the full width when it fits, else 128-lane tiles
+    lt = L if L <= L_TILE else L_TILE
+    L_p = -(-L // lt) * lt
     if J_p != J:
         x = jnp.pad(x, ((0, J_p - J), (0, 0)))
     if L_p != L:
@@ -65,15 +74,14 @@ def _autocorr_score(x: jnp.ndarray, lags: jnp.ndarray, *,
     out = pl.pallas_call(
         _kernel,
         out_shape=jax.ShapeDtypeStruct((J_p, L_p), jnp.float32),
-        grid=(J_p // bt, L_p // L_TILE),
-        in_specs=[
-            pl.BlockSpec((bt, N), lambda ji, li: (ji, 0)),
-            pl.BlockSpec((L_TILE,), lambda ji, li: (li,),
-                         memory_space=pltpu.SMEM),
-        ],
-        out_specs=pl.BlockSpec((bt, L_TILE), lambda ji, li: (ji, li)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(J_p // bt, L_p // lt),
+            in_specs=[pl.BlockSpec((bt, N), lambda ji, li, lags: (ji, 0))],
+            out_specs=pl.BlockSpec((bt, lt), lambda ji, li, lags: (ji, li)),
+        ),
         interpret=interpret,
-    )(x.astype(jnp.float32), lags.astype(jnp.int32))
+    )(lags.astype(jnp.int32), x.astype(jnp.float32))
     return out[:J, :L]
 
 
@@ -82,7 +90,7 @@ def autocorr_score(x: jnp.ndarray, lags: jnp.ndarray, *,
     """x: (J, N) f32 mean-removed rows; lags: (L,) int32 shared candidates.
 
     Returns (J, L) f32 unnormalized autocorrelation scores. Lags outside
-    [0, N) are clamped (callers mask their scores out). ``interpret=None``
+    [0, N] are clamped (callers mask their scores out). ``interpret=None``
     auto-detects: compiled on TPU, interpret mode (lowering validation)
     everywhere else.
     """

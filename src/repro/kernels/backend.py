@@ -20,6 +20,7 @@ a CPU host, it only routes dispatch.
 from __future__ import annotations
 
 import contextlib
+import os
 from typing import Iterator, Optional
 
 import jax
@@ -71,16 +72,16 @@ def force_backend(name: Optional[str]) -> Iterator[None]:
         _OVERRIDE = prev
 
 
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """jax >= 0.6 promotes shard_map to ``jax.shard_map`` (check_vma kwarg);
-    older releases ship it under jax.experimental with the check_rep
-    spelling. One shim for every row-sharded kernel/decide-plane wrapper."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+def use_compile_cache(default_dir) -> str:
+    """Keep JAX's persistent compilation cache where
+    ``JAX_COMPILATION_CACHE_DIR`` says (JAX reads that variable itself),
+    else in ``default_dir``. Callers pass a fixed path, so the cache's key
+    stays the same from one run to the next. Returns the directory used."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(default_dir))
+    return str(default_dir)
 
 
 def resolve_interpret(target: str, interpret: Optional[bool]) -> bool:

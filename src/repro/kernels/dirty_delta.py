@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import backend as kb
+
 ROW_TILE = 8          # blocks per program
 COL_TILE = 2048       # elements of the block dim per program (lane-aligned)
 
@@ -41,9 +43,8 @@ def _kernel(new_ref, old_ref, out_ref, acc):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def max_abs_delta(new: jnp.ndarray, old: jnp.ndarray, *,
-                  interpret: bool = True) -> jnp.ndarray:
-    """(n_blocks, block) x2 -> (n_blocks, 1) f32 max |new - old| per block."""
+def _max_abs_delta(new: jnp.ndarray, old: jnp.ndarray, *,
+                   interpret: bool) -> jnp.ndarray:
     nb, blk = new.shape
     rt = min(ROW_TILE, nb)
     ct = min(COL_TILE, blk)
@@ -64,3 +65,14 @@ def max_abs_delta(new: jnp.ndarray, old: jnp.ndarray, *,
         interpret=interpret,
     )(new, old)
     return out[:nb]
+
+
+def max_abs_delta(new: jnp.ndarray, old: jnp.ndarray, *,
+                  interpret=None) -> jnp.ndarray:
+    """(n_blocks, block) x2 -> (n_blocks, 1) f32 max |new - old| per block.
+
+    ``interpret=None`` auto-detects: compiled on TPU, interpret mode
+    (lowering validation) everywhere else.
+    """
+    return _max_abs_delta(new, old,
+                          interpret=kb.resolve_interpret("tpu", interpret))

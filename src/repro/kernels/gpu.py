@@ -12,9 +12,10 @@ restructure for a GPU:
     whole time reduction as one dot per weight tile — no cross-program
     accumulator, so no scratch. Mean removal uses the same rank-1
     column-sum correction as the TPU epilogue.
-  * ``autocorr_score``: identical body to the TPU kernel minus the SMEM
-    placement of the candidate-lag tile (Triton reads it from regular
-    memory).
+  * ``autocorr_score``: the same per-lag row products as the TPU kernel,
+    but shifted by a dynamic slice of the zero-extended rows and with the
+    candidate-lag tile read from regular memory (the TPU kernel instead
+    lane-rotates rows by lags scalar-prefetched into SMEM).
 
 Both share the TPU module's weight/table caches and numerics, and both run
 under interpret mode on non-GPU hosts — parity against ``kernels/ref.py``

@@ -11,7 +11,8 @@ tests can exercise a foreign row on any host):
                       (Pallas MXU matmul-DFT,  (Pallas Triton,         (jnp complex FFT)
                       fused mean removal)      dot per weight tile)
   autocorr_score      autocorr.autocorr_score  gpu.autocorr_score      ref.autocorr_score_
-                      (VMEM rows, SMEM lags)   (plain-Pallas body)     ref_xla (vmap slices)
+                      (VMEM rows, lane-roll    (plain-Pallas body)     ref_xla (vmap slices)
+                      per prefetched lag)
   ==================  =======================  ======================  =====================
 
 Pallas rows auto-detect ``interpret``: compiled on their physical target
@@ -62,8 +63,8 @@ def _row_sharded(fn, mesh, x: jnp.ndarray) -> jnp.ndarray:
     B_p = -(-B // n) * n
     if B_p != B:
         x = jnp.pad(x, ((0, B_p - B),) + ((0, 0),) * (x.ndim - 1))
-    out = kb.shard_map_compat(fn, mesh, in_specs=(P(axis),),
-                              out_specs=P(axis))(x)
+    out = jax.shard_map(fn, mesh=mesh, in_specs=(P(axis),),
+                        out_specs=P(axis), check_vma=False)(x)
     return out[:B]
 
 
@@ -79,7 +80,7 @@ def dirty_blocks(new: jnp.ndarray, old: jnp.ndarray,
     """
     if not jnp.issubdtype(new.dtype, jnp.floating):
         return jnp.any(new != old, axis=1)
-    d = _dd.max_abs_delta(new, old, interpret=_interpret())
+    d = _dd.max_abs_delta(new, old)
     return d[:, 0] > threshold
 
 
@@ -140,6 +141,10 @@ AUTOCORR_SCORE = {"tpu": _autocorr_tpu, "gpu": _autocorr_gpu,
                   "xla": _autocorr_xla}
 
 
+def autocorr_supported(n: int) -> bool:
+    return 0 < n <= _ac.MAX_N
+
+
 def autocorr_score(x: jnp.ndarray, lags: jnp.ndarray, *,
                    mesh=None) -> jnp.ndarray:
     """(J, N) rows x (L,) shared candidate lags -> (J, L) scores.
@@ -150,7 +155,7 @@ def autocorr_score(x: jnp.ndarray, lags: jnp.ndarray, *,
     Khinchin pocketfft pass, which beats any per-lag scoring on host.
     ``mesh`` partitions the job rows across devices.
     """
-    row = kernel_backend() if x.shape[1] <= _ac.MAX_N else "xla"
+    row = kernel_backend() if autocorr_supported(x.shape[1]) else "xla"
     fn = AUTOCORR_SCORE[row]
     if mesh is not None:
         return _row_sharded(lambda v: fn(v, lags), mesh, x)

@@ -18,14 +18,6 @@ from jax import lax
 
 from repro.configs.base import ArchConfig, MoEConfig
 
-# jax >= 0.6 promotes shard_map to jax.shard_map (check_vma kwarg); older
-# releases ship it under jax.experimental with the check_rep spelling.
-if hasattr(jax, "shard_map"):
-    _shard_map, _SHARD_MAP_KW = jax.shard_map, {"check_vma": False}
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
-
 Params = Dict[str, Any]
 
 
@@ -462,11 +454,11 @@ def _moe_ffn_sharded(params: Params, cfg: ArchConfig, x: jnp.ndarray,
         return y.reshape(Bq, Sq, d).astype(xl.dtype), aux
 
     x_spec = P(x_bspec, tp if seq_sharded else None, None)
-    out, aux = _shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, P(None, tp)) + w_specs,
         out_specs=(x_spec, P()),
-        **_SHARD_MAP_KW,
+        check_vma=False,
     )(x, params["router"], params["w_gate"], params["w_up"],
       params["w_down"])
 

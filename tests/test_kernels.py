@@ -195,6 +195,23 @@ def test_dispatch_falls_back_off_tile_shapes():
                                rtol=2e-4, atol=2e-2)
 
 
+def test_cycle_fit_warns_when_accelerator_falls_back_to_host():
+    """A window length off the kernel tiling still fits on the host, but on
+    an accelerator row it says so; an explicit host fit stays silent."""
+    import warnings
+
+    from repro.core import cycles
+    t = np.arange(200)
+    lm = (np.sin(2 * np.pi * t / 20) > 0).astype(np.int8)[None]
+    with ops.force_backend("tpu"), pytest.warns(RuntimeWarning,
+                                                match="power spectrum"):
+        fitted = cycles.fit_cycle_batch(lm)
+    assert fitted[0].period == 20
+    with ops.force_backend("tpu"), warnings.catch_warnings():
+        warnings.simplefilter("error")
+        assert cycles.fit_cycle_batch(lm, use_kernel=False)[0].period == 20
+
+
 def test_gpu_lowerings_direct_parity():
     """The Triton-lowered kernel bodies themselves (interpret mode here)
     against the shared oracles, without going through dispatch."""

@@ -94,6 +94,25 @@ def test_incremental_refresh_matches_full_reclassify(nb):
         assert job.origin_step == fleet.latest_step(i) - WINDOW + 1
 
 
+def test_blocked_classify_matches_one_piece(nb, monkeypatch):
+    """A fleet window spanning several classify row blocks (and a ragged
+    last block) must tick exactly like the classify in one piece."""
+    fleet, traces, t0s, rng = _fill_fleet()
+    whole = SurveillanceEngine()
+    _register_all(whole, nb, fleet)
+    res_whole = whole.tick(WINDOW - 1)
+    monkeypatch.setattr(characterize, "CLASSIFY_BLOCK", 200)
+    blocked = SurveillanceEngine()
+    _register_all(blocked, nb, fleet)
+    res_blocked = blocked.tick(WINDOW - 1)
+    assert len(traces) * WINDOW > 3 * characterize.CLASSIFY_BLOCK
+    assert res_blocked.remain == res_whole.remain
+    for jid, job in whole.jobs.items():
+        np.testing.assert_array_equal(blocked.jobs[jid].lm_series,
+                                      job.lm_series)
+        assert blocked.jobs[jid].model.period == job.model.period
+
+
 def test_staleness_epochs(nb):
     fleet, traces, _, _ = _fill_fleet()
     eng = SurveillanceEngine()
