@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.spans import scope
+
 # canonical workload classes (paper §6.2)
 CLASSES = ("CPU", "MEM", "IO", "IDLE")
 CPU, MEM, IO, IDLE = range(4)
@@ -98,16 +100,17 @@ def _nb_predict_lm(edges, ll, prior, x, *, block: int):
     def classify(r):
         return jnp.argmax(_nb_logprob(edges, ll, prior, r), axis=-1)
 
-    if n <= block:
-        cls = classify(rows)
-    else:
-        n_p = -(-n // block) * block
-        rows = jnp.pad(rows, ((0, n_p - n), (0, 0)))
-        cls = jax.lax.map(classify, rows.reshape(
-            n_p // block, block, -1)).reshape(-1)[:n]
-    lm = jnp.asarray(LM_SUITABLE, jnp.int8)[
-        jnp.clip(cls, 0, len(LM_SUITABLE) - 1)]
-    return lm.reshape(lead)
+    with scope("classify"):
+        if n <= block:
+            cls = classify(rows)
+        else:
+            n_p = -(-n // block) * block
+            rows = jnp.pad(rows, ((0, n_p - n), (0, 0)))
+            cls = jax.lax.map(classify, rows.reshape(
+                n_p // block, block, -1)).reshape(-1)[:n]
+        lm = jnp.asarray(LM_SUITABLE, jnp.int8)[
+            jnp.clip(cls, 0, len(LM_SUITABLE) - 1)]
+        return lm.reshape(lead)
 
 
 def fit(features: np.ndarray, labels: np.ndarray, *, n_bins: int = 16,
@@ -161,6 +164,14 @@ def classify_series_batch(nb: NaiveBayes, windows: np.ndarray,
     return classify_series(nb, windows)     # predict flattens leading axes
 
 
+def predict_lm(nb: NaiveBayes, windows: np.ndarray) -> jnp.ndarray:
+    """``classify_lm_batch`` dispatched, its (J, T) LM series left on the
+    device."""
+    return _nb_predict_lm(nb.bin_edges, nb.log_likelihood, nb.log_prior,
+                          jnp.asarray(windows, jnp.float32),
+                          block=CLASSIFY_BLOCK)
+
+
 def classify_lm_batch(nb: NaiveBayes, windows: np.ndarray) -> np.ndarray:
     """LM-only fleet classification: (J, T, F) -> (J, T) int8 {0=NLM,1=LM}.
 
@@ -168,10 +179,7 @@ def classify_lm_batch(nb: NaiveBayes, windows: np.ndarray) -> np.ndarray:
     argmax, same suitability table) but never materializes the (J, T, C)
     posterior — the surveillance tick's classify stage.
     """
-    return np.asarray(_nb_predict_lm(nb.bin_edges, nb.log_likelihood,
-                                     nb.log_prior,
-                                     jnp.asarray(windows, jnp.float32),
-                                     block=CLASSIFY_BLOCK))
+    return np.asarray(predict_lm(nb, windows))
 
 
 def primary_secondary(classes: np.ndarray) -> Tuple[int, Optional[int]]:

@@ -30,6 +30,7 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 from repro.kernels import ops as kops
+from repro.spans import enabled, span
 
 
 @dataclass
@@ -70,12 +71,15 @@ def _spectra(X: np.ndarray, use_kernel: Optional[bool],
     are already independent and host-resident.
     """
     n = X.shape[1]
-    if _resolve_kernel(use_kernel):
-        if kops.dft_supported(n):
-            return np.asarray(kops.power_spectrum(X, center=True, mesh=mesh))
-        _warn_host_fallback("power spectrum", n)
-    F = np.fft.rfft(X - X.mean(axis=1, keepdims=True), axis=1)
-    return (F.real ** 2 + F.imag ** 2).astype(np.float32)
+    with span("cycles.spectrum", rows=X.shape[0], n=n):
+        if _resolve_kernel(use_kernel):
+            if kops.dft_supported(n):
+                P = kops.power_spectrum(X, center=True, mesh=mesh)
+                with span("sync.spectrum"):
+                    return np.asarray(P)
+            _warn_host_fallback("power spectrum", n)
+        F = np.fft.rfft(X - X.mean(axis=1, keepdims=True), axis=1)
+        return (F.real ** 2 + F.imag ** 2).astype(np.float32)
 
 
 def power_spectrum(series: np.ndarray, use_kernel: Optional[bool] = None
@@ -148,38 +152,46 @@ def _refine_period_batch(X: np.ndarray, p0: np.ndarray, min_period: int,
     argmax is masked to its own window.
     """
     J, n = X.shape
-    X = np.asarray(X, np.float64)
-    Xc = X - X.mean(axis=1, keepdims=True)
-    p0 = np.asarray(p0, np.int64)
-    span = np.maximum(2, np.ceil(p0 * p0 / n).astype(np.int64) + 1)
-    lo = np.maximum(min_period, p0 - span)
-    hi = np.minimum(np.minimum(max_period, n - 1), p0 + span)
-    ok = hi >= lo
-    if not ok.any():
-        return p0.copy()
-    kernel = _resolve_kernel(use_kernel)
-    if kernel and not kops.autocorr_supported(n):
-        _warn_host_fallback("period refinement", n)
-        kernel = False
-    if kernel:
-        # Pallas kernel (TPU or GPU row of the dispatch table): fleet x
-        # shared candidate-lag grid in one call, optionally row-sharded
-        import jax.numpy as jnp
-        lags = np.arange(int(lo[ok].min()), int(hi[ok].max()) + 1)
-        R = np.asarray(kops.autocorr_score(
-            jnp.asarray(Xc, jnp.float32),
-            jnp.asarray(lags, jnp.int32), mesh=mesh)).astype(np.float64)
-    else:
-        # off-accelerator: Wiener-Khinchin on the zero-padded rows gives the
-        # exact linear autocorrelation R[j, p] = sum_t x[t] x[t+p] at EVERY
-        # lag in one vectorized pocketfft pass (interpret-mode Pallas is not
-        # a CPU hot path)
-        F = np.fft.rfft(Xc, 2 * n, axis=1)
-        R = np.fft.irfft(F.real ** 2 + F.imag ** 2, 2 * n, axis=1)[:, :n]
-        lags = np.arange(n)
-    valid = (lags[None, :] >= lo[:, None]) & (lags[None, :] <= hi[:, None])
-    best = lags[np.argmax(np.where(valid, R, -np.inf), axis=1)]
-    return np.where(ok, best, p0)
+    with span("cycles.refine", rows=J, n=n) as s:
+        X = np.asarray(X, np.float64)
+        Xc = X - X.mean(axis=1, keepdims=True)
+        p0 = np.asarray(p0, np.int64)
+        width = np.maximum(2, np.ceil(p0 * p0 / n).astype(np.int64) + 1)
+        lo = np.maximum(min_period, p0 - width)
+        hi = np.minimum(np.minimum(max_period, n - 1), p0 + width)
+        ok = hi >= lo
+        if not ok.any():
+            return p0.copy()
+        lag_lo, lag_hi = int(lo[ok].min()), int(hi[ok].max())
+        if enabled():
+            s.set_metadata(lag_lo=lag_lo, lag_hi=lag_hi)
+        kernel = _resolve_kernel(use_kernel)
+        if kernel and not kops.autocorr_supported(n):
+            _warn_host_fallback("period refinement", n)
+            kernel = False
+        if kernel:
+            # Pallas kernel (TPU or GPU row of the dispatch table): fleet x
+            # shared candidate-lag grid in one call, optionally row-sharded
+            import jax.numpy as jnp
+            lags = np.arange(lag_lo, lag_hi + 1)
+            scores = kops.autocorr_score(jnp.asarray(Xc, jnp.float32),
+                                         jnp.asarray(lags, jnp.int32),
+                                         mesh=mesh)
+            with span("sync.refine"):
+                R = np.asarray(scores)
+            R = R.astype(np.float64)
+        else:
+            # off-accelerator: Wiener-Khinchin on the zero-padded rows
+            # gives the exact linear autocorrelation R[j, p] = sum_t x[t]
+            # x[t+p] at EVERY lag in one vectorized pocketfft pass
+            # (interpret-mode Pallas is not a CPU hot path)
+            F = np.fft.rfft(Xc, 2 * n, axis=1)
+            R = np.fft.irfft(F.real ** 2 + F.imag ** 2, 2 * n, axis=1)[:, :n]
+            lags = np.arange(n)
+        valid = ((lags[None, :] >= lo[:, None])
+                 & (lags[None, :] <= hi[:, None]))
+        best = lags[np.argmax(np.where(valid, R, -np.inf), axis=1)]
+        return np.where(ok, best, p0)
 
 
 def _refine_period(x: np.ndarray, p0: int, min_period: int,
@@ -258,10 +270,11 @@ def fit_cycle_batch(classes_batch: np.ndarray, *, min_period: int = 2,
         return [CycleModel(0, 0.0, np.asarray(
             [1 if X[j].mean() >= 0.5 else 0], np.int8)) for j in range(J)]
     P = _spectra(X, use_kernel, mesh=mesh)
-    k_star, conf, found = _peak_pick(P, n, min_period, max_p,
-                                     total_power=_total_power(X))
-    p0 = np.round(n / np.maximum(k_star, 1)).astype(np.int64)
-    periods = np.where(found, p0, 1)
+    with span("cycles.peak_pick"):
+        k_star, conf, found = _peak_pick(P, n, min_period, max_p,
+                                         total_power=_total_power(X))
+        p0 = np.round(n / np.maximum(k_star, 1)).astype(np.int64)
+        periods = np.where(found, p0, 1)
     if found.any():
         refined = _refine_period_batch(X[found].astype(np.float64),
                                        p0[found], min_period, max_p,
@@ -269,20 +282,21 @@ def fit_cycle_batch(classes_batch: np.ndarray, *, min_period: int = 2,
         periods = periods.copy()
         periods[found] = refined
     out: List[CycleModel] = []
-    for j in range(J):
-        if not found[j]:
-            out.append(CycleModel(0, 0.0, np.asarray(
-                [1 if X[j].mean() >= 0.5 else 0], np.int8)))
-            continue
-        period = int(periods[j])
-        cls = np.asarray(classes_batch[j], np.int8)
-        array_lm, array_nlm, profile = decompose(cls, period)
-        if folded:
-            profile = fold_profile(cls, period)
-            idx = np.arange(period)
-            array_lm, array_nlm = idx[profile == 1], idx[profile != 1]
-        out.append(CycleModel(period, float(conf[j]), profile, array_lm,
-                              array_nlm))
+    with span("cycles.models"):
+        for j in range(J):
+            if not found[j]:
+                out.append(CycleModel(0, 0.0, np.asarray(
+                    [1 if X[j].mean() >= 0.5 else 0], np.int8)))
+                continue
+            period = int(periods[j])
+            cls = np.asarray(classes_batch[j], np.int8)
+            array_lm, array_nlm, profile = decompose(cls, period)
+            if folded:
+                profile = fold_profile(cls, period)
+                idx = np.arange(period)
+                array_lm, array_nlm = idx[profile == 1], idx[profile != 1]
+            out.append(CycleModel(period, float(conf[j]), profile, array_lm,
+                                  array_nlm))
     return out
 
 

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.cycles import CycleModel
+from repro.spans import scope
 
 
 def postpone(model: CycleModel, m_current: int) -> int:
@@ -49,18 +50,19 @@ def postpone_batch(profiles: jnp.ndarray, periods: jnp.ndarray,
     periods: (J,) int32; m_current: (J,) int32. Returns (J,) RemainTime.
     """
     J, P_max = profiles.shape
-    m_rel = m_current % jnp.maximum(periods, 1)
+    with scope("postpone"):
+        m_rel = m_current % jnp.maximum(periods, 1)
 
-    idx = jnp.arange(P_max)[None, :]
-    valid = idx < periods[:, None]
-    is_lm = (profiles == 1) & valid
-    # distance from m_rel to each LM phase, wrapping within the period
-    dist = (idx - m_rel[:, None]) % jnp.maximum(periods, 1)[:, None]
-    dist = jnp.where(is_lm, dist, jnp.iinfo(jnp.int32).max)
-    remain = jnp.min(dist, axis=1)
-    none_lm = ~jnp.any(is_lm, axis=1)
-    remain = jnp.where(none_lm, periods, remain)       # all-NLM backoff
-    return jnp.where(periods <= 1, 0, remain).astype(jnp.int32)
+        idx = jnp.arange(P_max)[None, :]
+        valid = idx < periods[:, None]
+        is_lm = (profiles == 1) & valid
+        # distance from m_rel to each LM phase, wrapping within the period
+        dist = (idx - m_rel[:, None]) % jnp.maximum(periods, 1)[:, None]
+        dist = jnp.where(is_lm, dist, jnp.iinfo(jnp.int32).max)
+        remain = jnp.min(dist, axis=1)
+        none_lm = ~jnp.any(is_lm, axis=1)
+        remain = jnp.where(none_lm, periods, remain)       # all-NLM backoff
+        return jnp.where(periods <= 1, 0, remain).astype(jnp.int32)
 
 
 postpone_batch_jit = jax.jit(postpone_batch)

@@ -31,6 +31,7 @@ import numpy as np
 from repro.core.strunk import (MigrationOutcome, XEN_MAX_ROUNDS,
                                XEN_STOP_DIRTY_PAGES, XEN_STOP_TOTAL_FACTOR)
 from repro.kernels import ops as kops
+from repro.spans import enabled, scope, span
 
 
 @dataclass(frozen=True)
@@ -59,8 +60,9 @@ def _blocks(leaf: jnp.ndarray, nb: int, block: int) -> jnp.ndarray:
 def _leaf_dirty(new: jnp.ndarray, old: jnp.ndarray, block: int) -> jnp.ndarray:
     """Leaf pair (same shape) -> (nb,) bool dirty mask over its flat blocks."""
     nb = -(-new.size // block)
-    return kops.dirty_blocks(_blocks(new, nb, block),
-                             _blocks(old.astype(new.dtype), nb, block))
+    with scope("dirty_scan"):
+        return kops.dirty_blocks(_blocks(new, nb, block),
+                                 _blocks(old.astype(new.dtype), nb, block))
 
 
 @partial(jax.jit, static_argnums=(3,), donate_argnums=(1,))
@@ -70,20 +72,27 @@ def _leaf_merge(new: jnp.ndarray, old: jnp.ndarray, dirty: jnp.ndarray,
     ``old``'s buffer is donated to the result, so a round holds one shadow
     copy of the state, not two."""
     nb = dirty.shape[0]
-    out = jnp.where(dirty[:, None], _blocks(new, nb, block),
-                    _blocks(old.astype(new.dtype), nb, block))
-    return out.reshape(-1)[: new.size].reshape(old.shape).astype(old.dtype)
+    with scope("merge"):
+        out = jnp.where(dirty[:, None], _blocks(new, nb, block),
+                        _blocks(old.astype(new.dtype), nb, block))
+        return out.reshape(-1)[: new.size].reshape(old.shape).astype(
+            old.dtype)
 
 
 def dirty_scan(live, shadow, block: int) -> Tuple[List[jnp.ndarray], int, int]:
     """Per-leaf dirty masks + (dirty_blocks, dirty_bytes) totals."""
-    masks, n_dirty, n_bytes = [], 0, 0
-    for new, old in zip(jax.tree.leaves(live), jax.tree.leaves(shadow)):
-        m = _leaf_dirty(new, old, block)
-        masks.append(m)
-        d = int(jnp.sum(m))
-        n_dirty += d
-        n_bytes += d * block * new.dtype.itemsize
+    masks, n_dirty, n_bytes, syncs = [], 0, 0, 0
+    leaves = jax.tree.leaves(live)
+    with span("precopy.scan", leaves=len(leaves)) as s:
+        for new, old in zip(leaves, jax.tree.leaves(shadow)):
+            m = _leaf_dirty(new, old, block)
+            masks.append(m)
+            d = int(jnp.sum(m))
+            syncs += 1
+            n_dirty += d
+            n_bytes += d * block * new.dtype.itemsize
+        if enabled():
+            s.set_metadata(syncs=syncs, dirty_blocks=n_dirty)
     return masks, n_dirty, n_bytes
 
 
@@ -99,8 +108,9 @@ def merge_dirty(live, shadow, masks: List[jnp.ndarray], block: int):
         return n
 
     leaves = jax.tree.leaves(shadow)
-    merged = [_leaf_merge(align(n, o), o, m, block)
-              for n, o, m in zip(jax.tree.leaves(live), leaves, masks)]
+    with span("precopy.merge", leaves=len(leaves)):
+        merged = [_leaf_merge(align(n, o), o, m, block)
+                  for n, o, m in zip(jax.tree.leaves(live), leaves, masks)]
     return jax.tree.unflatten(jax.tree.structure(shadow), merged)
 
 
@@ -135,41 +145,48 @@ def migrate(get_state: Callable[[], Any],
     place = placement or (lambda t: t)
     live = get_state()
     v_mem = total_bytes(live)
+    with span("precopy.migrate", state_bytes=v_mem,
+              leaves=len(jax.tree.leaves(live))) as s:
+        # round 0: full copy (iterative-copy stage, first iteration)
+        shadow = place(jax.tree.map(jnp.array, live))
+        sent = v_mem
+        sim_t = v_mem / cfg.bandwidth
+        per_round = [v_mem]
+        rounds = 1
+        reason = "max_rounds"
 
-    # round 0: full copy (iterative-copy stage, first iteration)
-    shadow = place(jax.tree.map(jnp.array, live))
-    sent = v_mem
-    sim_t = v_mem / cfg.bandwidth
-    per_round = [v_mem]
-    rounds = 1
-    reason = "max_rounds"
+        while True:
+            with span("precopy.round", round=rounds):
+                if step_fn is not None:    # job keeps running during the copy
+                    for _ in range(cfg.steps_per_round):
+                        step_fn()
+                live = get_state()
+                masks, n_dirty, n_bytes = dirty_scan(live, shadow,
+                                                     cfg.block_elems)
+                if n_dirty <= cfg.stop_dirty_blocks:
+                    reason = "dirty_low"
+                    break
+                if rounds >= cfg.max_rounds:
+                    reason = "max_rounds"
+                    break
+                if sent + n_bytes > cfg.stop_total_factor * v_mem:
+                    reason = "total_cap"
+                    break
+                shadow = merge_dirty(live, shadow, masks, cfg.block_elems)
+                sent += n_bytes
+                sim_t += n_bytes / cfg.bandwidth
+                per_round.append(n_bytes)
+                rounds += 1
 
-    while True:
-        if step_fn is not None:            # job keeps running during the copy
-            for _ in range(cfg.steps_per_round):
-                step_fn()
-        live = get_state()
-        masks, n_dirty, n_bytes = dirty_scan(live, shadow, cfg.block_elems)
-        if n_dirty <= cfg.stop_dirty_blocks:
-            reason = "dirty_low"
-            break
-        if rounds >= cfg.max_rounds:
-            reason = "max_rounds"
-            break
-        if sent + n_bytes > cfg.stop_total_factor * v_mem:
-            reason = "total_cap"
-            break
-        shadow = merge_dirty(live, shadow, masks, cfg.block_elems)
-        sent += n_bytes
-        sim_t += n_bytes / cfg.bandwidth
-        per_round.append(n_bytes)
-        rounds += 1
-
-    # stop-and-copy: job paused; transfer the final dirty set
-    live = get_state()
-    masks, n_dirty, n_bytes = dirty_scan(live, shadow, cfg.block_elems)
-    shadow = merge_dirty(live, shadow, masks, cfg.block_elems)
-    shadow = jax.block_until_ready(shadow)
+        # stop-and-copy: job paused; transfer the final dirty set
+        with span("precopy.stop_copy"):
+            live = get_state()
+            masks, n_dirty, n_bytes = dirty_scan(live, shadow,
+                                                 cfg.block_elems)
+            shadow = merge_dirty(live, shadow, masks, cfg.block_elems)
+            shadow = jax.block_until_ready(shadow)
+        if enabled():
+            s.set_metadata(rounds=rounds)
     downtime = n_bytes / cfg.bandwidth
     sent += n_bytes
     sim_t += downtime

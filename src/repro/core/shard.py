@@ -38,6 +38,7 @@ import numpy as np
 
 from repro.core import characterize
 from repro.core import postpone as pp
+from repro.spans import span
 
 
 def device_count() -> int:
@@ -87,17 +88,20 @@ def classify_lm(nb: characterize.NaiveBayes, windows, mesh=None) -> np.ndarray:
     NB decisions are per-sample.
     """
     if mesh is None:
-        return characterize.classify_lm_batch(nb, windows)
-    from jax.sharding import PartitionSpec as P
-    axis = mesh.axis_names[0]
-    x, J = _pad_rows(jnp.asarray(windows, jnp.float32),
-                     int(mesh.devices.size))
-    body = functools.partial(characterize._nb_predict_lm,
-                             block=characterize.CLASSIFY_BLOCK)
-    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), P(), P(), P(axis)),
-                       out_specs=P(axis), check_vma=False)
-    return np.asarray(fn(nb.bin_edges, nb.log_likelihood, nb.log_prior,
-                         x))[:J]
+        lm = characterize.predict_lm(nb, windows)
+    else:
+        from jax.sharding import PartitionSpec as P
+        axis = mesh.axis_names[0]
+        x, _ = _pad_rows(jnp.asarray(windows, jnp.float32),
+                         int(mesh.devices.size))
+        body = functools.partial(characterize._nb_predict_lm,
+                                 block=characterize.CLASSIFY_BLOCK)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P(), P(), P(), P(axis)),
+                           out_specs=P(axis), check_vma=False)
+        lm = fn(nb.bin_edges, nb.log_likelihood, nb.log_prior, x)
+    with span("sync.classify"):
+        return np.asarray(lm)[:len(windows)]
 
 
 def postpone_rows(profiles, periods, m_now, mesh=None) -> jnp.ndarray:

@@ -66,6 +66,7 @@ import numpy as np
 from repro.core import characterize, cycles, postpone as pp
 from repro.core import shard as shardlib
 from repro.core.telemetry import TelemetryBuffer
+from repro.spans import enabled, span
 
 
 def _pow2(n: int) -> int:
@@ -161,6 +162,8 @@ class SurveillanceEngine:
         self.mesh = shardlib.decide_mesh(shards)
         self.jobs: Dict[str, SurveilledJob] = {}
         self._decide_cache: Optional[Tuple] = None
+        #: batched refit groups run (``_refresh_group`` calls), ever
+        self.groups_refit = 0
 
     # -- registration -------------------------------------------------------
     def register(self, job_id: str, telemetry, nb: characterize.NaiveBayes,
@@ -239,82 +242,92 @@ class SurveillanceEngine:
         """Recompute the cycle fit of every stale (or ``force``d) job in
         one batched pipeline per (classifier, window-length) group.
         Returns the number of jobs refit."""
-        jobs = ([self.jobs[i] for i in job_ids] if job_ids is not None
-                else list(self.jobs.values()))
-        if not jobs:
-            return 0
-        latest = self._latest_steps(jobs)
-        todo = [(job, ls) for job, ls in zip(jobs, latest)
-                if (force and ls >= 0
-                    and len(job.telemetry) >= self.min_samples)
-                or (not force and self._stale(job, ls))]
-        if not todo:
-            return 0
-        groups: Dict[tuple, List[tuple]] = {}
-        for job, ls in todo:
-            m = min(job.window, len(job.telemetry))
-            delta = int(ls) - job.fitted_step
-            # incremental classification: NB is stateless per sample, so a
-            # slid window only needs its NEW tail classified — the cached
-            # lm_series supplies the overlap (telemetry steps are assumed
-            # dense, one sample per step, as the recorder produces them)
-            splice = (job.fitted_step >= 0 and len(job.lm_series) == m
-                      and 0 <= delta < m)
-            tail = min(m, _pow2(max(delta, 1))) if splice else m
-            groups.setdefault((id(job.nb), m, tail), []).append((job, ls))
+        with span("surveil.stale_scan") as s:
+            jobs = ([self.jobs[i] for i in job_ids] if job_ids is not None
+                    else list(self.jobs.values()))
+            latest = self._latest_steps(jobs)
+            todo = [(job, ls) for job, ls in zip(jobs, latest)
+                    if (force and ls >= 0
+                        and len(job.telemetry) >= self.min_samples)
+                    or (not force and self._stale(job, ls))]
+            groups: Dict[tuple, List[tuple]] = {}
+            for job, ls in todo:
+                m = min(job.window, len(job.telemetry))
+                delta = int(ls) - job.fitted_step
+                # incremental classification: NB is stateless per sample,
+                # so a slid window only needs its NEW tail classified — the
+                # cached lm_series supplies the overlap (telemetry steps
+                # are assumed dense, one sample per step, as the recorder
+                # produces them)
+                splice = (job.fitted_step >= 0 and len(job.lm_series) == m
+                          and 0 <= delta < m)
+                tail = min(m, _pow2(max(delta, 1))) if splice else m
+                groups.setdefault((id(job.nb), m, tail), []).append((job, ls))
+            if enabled():
+                s.set_metadata(jobs=len(jobs), stale=len(todo))
         for (_, m, tail), entries in groups.items():
-            self._refresh_group([j for j, _ in entries],
-                                np.asarray([ls for _, ls in entries]),
-                                m, tail)
+            with span("surveil.refit", rows=len(entries),
+                      rows_padded=_pow2(len(entries)), tail=tail, window=m):
+                self._refresh_group([j for j, _ in entries],
+                                    np.asarray([ls for _, ls in entries]),
+                                    m, tail)
+            self.groups_refit += 1
         return len(todo)
 
     def _refresh_group(self, jobs: List[SurveilledJob],
                        latest: np.ndarray, m: int, tail: int) -> None:
         G = len(jobs)
-        # masked gather: NaN dropout samples come back zero-filled (the
-        # batched NB/FFT stays finite) with their invalidity recorded, so
-        # starved rows can be demoted instead of fit to hole-filled data
-        W, counts, valid = TelemetryBuffer.window_matrix(
-            [j.telemetry for j in jobs], tail,
-            return_mask=True)                               # (G, tail, F)
-        coverage = valid.sum(axis=1) / np.maximum(counts, 1)
-        # bucket BOTH batch axes so the jitted NB doesn't retrace per stale
-        # subset (job axis) or per history length (time axis — zero rows at
-        # the front classify to garbage and are sliced off; NB is per-sample)
-        G_p, T_p = _pow2(G), _pow2(tail)
-        if G_p != G or T_p != tail:
-            Wp = np.zeros((G_p, T_p, W.shape[2]))
-            Wp[:G, T_p - tail:] = W
-            W = Wp
-        # lm-only classify: same jitted argmax as classify_series_batch
-        # (bit-identical lm), no (G, T, C) posterior — optionally sharded
-        lm_tail = shardlib.classify_lm(jobs[0].nb, W, self.mesh)
-        lm_tail = lm_tail[:G, T_p - tail:]
-        if tail == m:
-            LM = lm_tail
-        else:
-            LM = np.empty((G, m), np.int8)
-            for i, (job, ls) in enumerate(zip(jobs, latest)):
-                d = int(ls) - job.fitted_step
-                LM[i, : m - d] = job.lm_series[d:]
-                if d:
-                    LM[i, m - d:] = lm_tail[i, tail - d:]
+        with span("surveil.gather"):
+            # masked gather: NaN dropout samples come back zero-filled (the
+            # batched NB/FFT stays finite) with their invalidity recorded,
+            # so starved rows can be demoted instead of fit to hole-filled
+            # data
+            W, counts, valid = TelemetryBuffer.window_matrix(
+                [j.telemetry for j in jobs], tail,
+                return_mask=True)                           # (G, tail, F)
+            coverage = valid.sum(axis=1) / np.maximum(counts, 1)
+            # bucket BOTH batch axes so the jitted NB doesn't retrace per
+            # stale subset (job axis) or per history length (time axis —
+            # zero rows at the front classify to garbage and are sliced
+            # off; NB is per-sample)
+            G_p, T_p = _pow2(G), _pow2(tail)
+            if G_p != G or T_p != tail:
+                Wp = np.zeros((G_p, T_p, W.shape[2]))
+                Wp[:G, T_p - tail:] = W
+                W = Wp
+        with span("surveil.classify"):
+            # lm-only classify: same jitted argmax as classify_series_batch
+            # (bit-identical lm), no (G, T, C) posterior — optionally
+            # sharded
+            lm_tail = shardlib.classify_lm(jobs[0].nb, W, self.mesh)
+            lm_tail = lm_tail[:G, T_p - tail:]
+        with span("surveil.splice"):
+            if tail == m:
+                LM = lm_tail
+            else:
+                LM = np.empty((G, m), np.int8)
+                for i, (job, ls) in enumerate(zip(jobs, latest)):
+                    d = int(ls) - job.fitted_step
+                    LM[i, : m - d] = job.lm_series[d:]
+                    if d:
+                        LM[i, m - d:] = lm_tail[i, tail - d:]
         models = cycles.fit_cycle_batch(LM, folded=self.folded,
                                         use_kernel=self.use_kernel,
                                         mesh=self.mesh)
-        for i, (job, model, lm_row, ls) in enumerate(
-                zip(jobs, models, LM, latest)):
-            if coverage[i] < self.min_coverage:
-                # blackout-starved window: a cycle fit over zero-filled
-                # holes is noise — demote to acyclic (same shape as the
-                # not-found branch of fit_cycle_batch) until telemetry
-                # recovers and a later refit sees real samples again
-                model = cycles.CycleModel(0, 0.0, np.asarray(
-                    [1 if lm_row.mean() >= 0.5 else 0], np.int8))
-            job.model = model
-            job.lm_series = lm_row
-            job.origin_step = int(ls) - m + 1
-            job.fitted_step = int(ls)
+        with span("surveil.assign"):
+            for i, (job, model, lm_row, ls) in enumerate(
+                    zip(jobs, models, LM, latest)):
+                if coverage[i] < self.min_coverage:
+                    # blackout-starved window: a cycle fit over zero-filled
+                    # holes is noise — demote to acyclic (same shape as the
+                    # not-found branch of fit_cycle_batch) until telemetry
+                    # recovers and a later refit sees real samples again
+                    model = cycles.CycleModel(0, 0.0, np.asarray(
+                        [1 if lm_row.mean() >= 0.5 else 0], np.int8))
+                job.model = model
+                job.lm_series = lm_row
+                job.origin_step = int(ls) - m + 1
+                job.fitted_step = int(ls)
         self._decide_cache = None       # packed Alg.2 operands went stale
 
     def refresh_model(self, job_id: str, *, force: bool = False
@@ -331,23 +344,26 @@ class SurveillanceEngine:
         invalidated only by register/unregister/refit, so an all-fresh
         tick does no per-job Python work past the staleness scan."""
         if self._decide_cache is None:
-            fitted = [j for j in self.jobs.values() if j.model is not None]
-            if not fitted:
-                self._decide_cache = ((), None, None, None, {})
-            else:
-                p_max = max((j.model.period for j in fitted
-                             if j.model.period > 1), default=1)
-                # bucket both axes: jit cache stays O(log J * log P)
-                J_p, P_p = _pow2(len(fitted)), _pow2(max(p_max, 1))
-                profiles, periods = pp.pack_fleet(
-                    [j.model for j in fitted], n_jobs=J_p, p_max=P_p)
-                origins = np.zeros(J_p, np.int64)
-                origins[: len(fitted)] = [j.origin_step for j in fitted]
-                self._decide_cache = (tuple(j.job_id for j in fitted),
-                                      origins, profiles, periods,
-                                      {j.job_id: float(j.model.confidence)
-                                       for j in fitted})
+            with span("surveil.pack_fleet") as s:
+                self._decide_cache = self._pack()
+                if enabled():
+                    s.set_metadata(rows=len(self._decide_cache[0]))
         return self._decide_cache
+
+    def _pack(self) -> Tuple:
+        fitted = [j for j in self.jobs.values() if j.model is not None]
+        if not fitted:
+            return ((), None, None, None, {})
+        p_max = max((j.model.period for j in fitted
+                     if j.model.period > 1), default=1)
+        # bucket both axes: jit cache stays O(log J * log P)
+        J_p, P_p = _pow2(len(fitted)), _pow2(max(p_max, 1))
+        profiles, periods = pp.pack_fleet(
+            [j.model for j in fitted], n_jobs=J_p, p_max=P_p)
+        origins = np.zeros(J_p, np.int64)
+        origins[: len(fitted)] = [j.origin_step for j in fitted]
+        return (tuple(j.job_id for j in fitted), origins, profiles, periods,
+                {j.job_id: float(j.model.confidence) for j in fitted})
 
     def next_trough(self, job_ids: List[str], now_step: int
                     ) -> Dict[str, Optional[int]]:
@@ -380,17 +396,32 @@ class SurveillanceEngine:
         are captured at dispatch). Padding rows (period 0) decide to 0 and
         are sliced off before the dict is built.
         """
-        refitted = self.refresh()
+        with span("surveil.tick") as s:
+            groups = self.groups_refit
+            refitted = self.refresh()
+            packed = self._decide_cache is None
+            res = self._decide(now_step, refitted)
+            if enabled():
+                s.set_metadata(jobs=len(self.jobs), refitted=refitted,
+                               groups=self.groups_refit - groups,
+                               packed=int(packed))
+        return res
+
+    def _decide(self, now_step: int, refitted: int) -> TickResult:
         ids, origins, profiles, periods, conf = self._packed_fleet()
         if not ids:
             return TickResult({}, refitted, 0)
-        m_now = (now_step - origins).astype(np.int32)   # one vector op
-        remain_dev = shardlib.postpone_rows(profiles, periods, m_now,
-                                            self.mesh)
+        with span("surveil.decide"):
+            m_now = (now_step - origins).astype(np.int32)   # one vector op
+            remain_dev = shardlib.postpone_rows(profiles, periods, m_now,
+                                                self.mesh)
         J = len(ids)
 
         def materialize(ids=ids, dev=remain_dev, J=J) -> Dict[str, int]:
-            return dict(zip(ids, np.asarray(dev)[:J].tolist()))
+            with span("surveil.remain"):
+                with span("sync.remain"):
+                    remain = np.asarray(dev)
+                return dict(zip(ids, remain[:J].tolist()))
 
         if self.overlap:
             return TickResult(None, refitted, J, conf, _thunk=materialize)

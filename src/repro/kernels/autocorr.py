@@ -33,6 +33,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import backend as kb
+from repro.spans import scope
 
 B_TILE = 32
 L_TILE = 128
@@ -67,22 +68,26 @@ def _autocorr_score(x: jnp.ndarray, lags: jnp.ndarray, *,
     # one lag tile of the full width when it fits, else 128-lane tiles
     lt = L if L <= L_TILE else L_TILE
     L_p = -(-L // lt) * lt
-    if J_p != J:
-        x = jnp.pad(x, ((0, J_p - J), (0, 0)))
-    if L_p != L:
-        lags = jnp.pad(lags, (0, L_p - L))
-    out = pl.pallas_call(
-        _kernel,
-        out_shape=jax.ShapeDtypeStruct((J_p, L_p), jnp.float32),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(J_p // bt, L_p // lt),
-            in_specs=[pl.BlockSpec((bt, N), lambda ji, li, lags: (ji, 0))],
-            out_specs=pl.BlockSpec((bt, lt), lambda ji, li, lags: (ji, li)),
-        ),
-        interpret=interpret,
-    )(lags.astype(jnp.int32), x.astype(jnp.float32))
-    return out[:J, :L]
+    with scope("autocorr"):
+        if J_p != J:
+            x = jnp.pad(x, ((0, J_p - J), (0, 0)))
+        if L_p != L:
+            lags = jnp.pad(lags, (0, L_p - L))
+        out = pl.pallas_call(
+            _kernel,
+            out_shape=jax.ShapeDtypeStruct((J_p, L_p), jnp.float32),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(J_p // bt, L_p // lt),
+                in_specs=[pl.BlockSpec((bt, N),
+                                       lambda ji, li, lags: (ji, 0))],
+                out_specs=pl.BlockSpec((bt, lt),
+                                       lambda ji, li, lags: (ji, li)),
+            ),
+            interpret=interpret,
+            name="autocorr_score",
+        )(lags.astype(jnp.int32), x.astype(jnp.float32))
+        return out[:J, :L]
 
 
 def autocorr_score(x: jnp.ndarray, lags: jnp.ndarray, *,

@@ -43,6 +43,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import backend as kb
+from repro.spans import scope
 
 B_TILE = 8
 F_TILE = 128
@@ -136,26 +137,29 @@ def _dft_power(x: jnp.ndarray, *, center: bool,
                        .astype(np.float32)[None, :])
     bt = min(B_TILE, B)
     B_p = -(-B // bt) * bt
-    if B_p != B:
-        x = jnp.pad(x, ((0, B_p - B), (0, 0)))
-    out = pl.pallas_call(
-        functools.partial(_kernel, n=N, center=center),
-        out_shape=jax.ShapeDtypeStruct((B_p, N), jnp.float32),
-        grid=(B_p // bt, N // F_TILE, N // T_TILE),
-        in_specs=[
-            pl.BlockSpec((bt, T_TILE), lambda bi, fi, ti: (bi, ti)),
-            pl.BlockSpec((T_TILE, F_TILE), lambda bi, fi, ti: (ti, fi)),
-            pl.BlockSpec((T_TILE, F_TILE), lambda bi, fi, ti: (ti, fi)),
-            pl.BlockSpec((1, F_TILE), lambda bi, fi, ti: (0, fi)),
-            pl.BlockSpec((1, F_TILE), lambda bi, fi, ti: (0, fi)),
-        ],
-        out_specs=pl.BlockSpec((bt, F_TILE), lambda bi, fi, ti: (bi, fi)),
-        scratch_shapes=[pltpu.VMEM((bt, F_TILE), jnp.float32),
-                        pltpu.VMEM((bt, F_TILE), jnp.float32),
-                        pltpu.VMEM((bt, 1), jnp.float32)],
-        interpret=interpret,
-    )(x, cos_w, sin_w, csum, ssum)
-    return out[:B]
+    with scope("spectrum"):
+        if B_p != B:
+            x = jnp.pad(x, ((0, B_p - B), (0, 0)))
+        out = pl.pallas_call(
+            functools.partial(_kernel, n=N, center=center),
+            out_shape=jax.ShapeDtypeStruct((B_p, N), jnp.float32),
+            grid=(B_p // bt, N // F_TILE, N // T_TILE),
+            in_specs=[
+                pl.BlockSpec((bt, T_TILE), lambda bi, fi, ti: (bi, ti)),
+                pl.BlockSpec((T_TILE, F_TILE), lambda bi, fi, ti: (ti, fi)),
+                pl.BlockSpec((T_TILE, F_TILE), lambda bi, fi, ti: (ti, fi)),
+                pl.BlockSpec((1, F_TILE), lambda bi, fi, ti: (0, fi)),
+                pl.BlockSpec((1, F_TILE), lambda bi, fi, ti: (0, fi)),
+            ],
+            out_specs=pl.BlockSpec((bt, F_TILE),
+                                   lambda bi, fi, ti: (bi, fi)),
+            scratch_shapes=[pltpu.VMEM((bt, F_TILE), jnp.float32),
+                            pltpu.VMEM((bt, F_TILE), jnp.float32),
+                            pltpu.VMEM((bt, 1), jnp.float32)],
+            interpret=interpret,
+            name="dft_power",
+        )(x, cos_w, sin_w, csum, ssum)
+        return out[:B]
 
 
 def dft_power(x: jnp.ndarray, *, center: bool = False,

@@ -63,6 +63,7 @@ def _max_abs_delta(new: jnp.ndarray, old: jnp.ndarray, *,
         out_specs=pl.BlockSpec((rt, 1), lambda ri, ci: (ri, 0)),
         scratch_shapes=[pltpu.VMEM((rt, 1), jnp.float32)],
         interpret=interpret,
+        name="max_abs_delta",
     )(new, old)
     return out[:nb]
 
