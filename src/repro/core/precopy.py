@@ -9,7 +9,8 @@ set. The result is bit-exact: the destination pytree equals the source at
 the moment of the final copy (tested in tests/test_precopy.py).
 
 Block diffing is the memory-bound hot loop -> Pallas kernel
-(``repro.kernels.dirty_delta``), with a jnp fallback on hosts without it.
+(``repro.kernels.dirty_delta``), which reads each float leaf and its shadow
+in place, in the leaf's own layout.
 
 Time accounting is dual: wall-clock (real copies) and a bandwidth model
 (bytes / link-bandwidth) so fleet-scale costs can be projected from smoke
@@ -58,11 +59,17 @@ def _blocks(leaf: jnp.ndarray, nb: int, block: int) -> jnp.ndarray:
 
 @partial(jax.jit, static_argnums=(2,))
 def _leaf_dirty(new: jnp.ndarray, old: jnp.ndarray, block: int) -> jnp.ndarray:
-    """Leaf pair (same shape) -> (nb,) bool dirty mask over its flat blocks."""
-    nb = -(-new.size // block)
+    """Leaf pair (same shape) -> (nb,) bool dirty mask over its flat blocks.
+    A leaf the kernel can view as a bitcast of itself (``kops.
+    reads_in_place``) is read where it lies; any other, and every integer
+    leaf (compared exactly), through the padded block view."""
     with scope("dirty_scan"):
+        old = old.astype(new.dtype)
+        if kops.reads_in_place(new, block):
+            return kops.leaf_dirty_blocks(new, old, block)
+        nb = -(-new.size // block)
         return kops.dirty_blocks(_blocks(new, nb, block),
-                                 _blocks(old.astype(new.dtype), nb, block))
+                                 _blocks(old, nb, block))
 
 
 @partial(jax.jit, static_argnums=(3,), donate_argnums=(1,))
@@ -92,7 +99,9 @@ def dirty_scan(live, shadow, block: int) -> Tuple[List[jnp.ndarray], int, int]:
             n_dirty += d
             n_bytes += d * block * new.dtype.itemsize
         if enabled():
-            s.set_metadata(syncs=syncs, dirty_blocks=n_dirty)
+            s.set_metadata(syncs=syncs, dirty_blocks=n_dirty,
+                           inplace=sum(kops.reads_in_place(leaf, block)
+                                       for leaf in leaves))
     return masks, n_dirty, n_bytes
 
 

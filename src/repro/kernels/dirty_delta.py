@@ -1,79 +1,191 @@
 """Dirty-block scan kernel — the pre-copy inner loop (DESIGN.md §5).
 
-Given the live view and the shadow (last-copied) view of a state shard as
-(n_blocks, block) tiles, emit the per-block max |delta| so the migration
-engine can mark dirty "pages". Purely memory-bound (2 streaming reads, tiny
-write): the Pallas value is the explicit HBM->VMEM pipeline; block tiles are
-sized so two input tiles + accumulator fit comfortably in VMEM.
+A block of a leaf is dirty when any of its flat elements changed since the
+last copy: max |new - old| over the block, in float32, above 0. The scan
+is memory-bound (two streaming reads, a small write), so the kernel reads
+the leaf and its shadow where they lie, through a view that is a bitcast
+of the leaf in the chip's tiled layout: the minor dim (``L`` lanes) and the
+second-minor dim stay, the leading dims collapse into rows, or into slabs
+where the second-minor dim is below one sublane tile (a KV cache's 8 heads
+in bfloat16). No copy of a leaf is made, flattened or padded.
 
-Grid: (row_tiles, col_tiles); col dim innermost so the row accumulator lives
-in VMEM scratch across the column sweep and the (n_blocks, 1) result is
-written once per row tile.
+Each grid step reads a tile of about ``TILE_BYTES`` of each input and
+writes float32 partial maxima, the same number for every flat block, in
+the flat order of the data; a block's max is the max of its partials. With
+``k = L / 128`` lane chunks in a row and ``c = block / 128`` in a block:
+
+- ``rows``: a block holds whole rows (``k`` divides ``c``). Every chunk of
+  a row, and ``group`` consecutive rows, fold elementwise into one
+  128-lane partial; no lane moves.
+- ``segments``: a row is longer than a block, or blocks straddle rows (the
+  vocabulary-wide minor dim of an LM head). Each chunk reduces across its
+  128 lanes to one segment max, laid along the lanes of the output in the
+  data's order, and a block is the max of its ``c`` segments.
+- ``slabs``: a block holds whole (second-minor, minor) slabs; ``group``
+  slabs fold elementwise, then their rows.
+
+A shape none of these fit (a minor dim or a block that is no multiple of
+128) has no plan; ``max_abs_delta`` serves the caller's ``(n_blocks,
+block)`` view of it, padded to whole lane chunks.
 """
 from __future__ import annotations
 
 import functools
+import math
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import backend as kb
 
-ROW_TILE = 8          # blocks per program
-COL_TILE = 2048       # elements of the block dim per program (lane-aligned)
+LANES = 128
+#: bytes of each input one grid step reads; two inputs, double-buffered,
+#: stay well inside the default scoped VMEM
+TILE_BYTES = 1 << 20
+#: lane chunks one grid step reduces to segment maxima: a short unrolled
+#: body (the LM head's 723 chunks, unrolled, took seconds to trace in
+#: every process's set-up; a loop over them read a third as fast)
+SEGMENT_CHUNKS = 16
 
 
-def _kernel(new_ref, old_ref, out_ref, acc):
-    ci = pl.program_id(1)
-    nc = pl.num_programs(1)
-
-    @pl.when(ci == 0)
-    def _init():
-        acc[...] = jnp.zeros_like(acc)
-
-    d = jnp.abs(new_ref[...].astype(jnp.float32)
-                - old_ref[...].astype(jnp.float32))
-    acc[...] = jnp.maximum(acc[...], jnp.max(d, axis=1, keepdims=True))
-
-    @pl.when(ci == nc - 1)
-    def _emit():
-        out_ref[...] = acc[...]
+class Plan(NamedTuple):
+    mode: str                  # rows | segments | slabs
+    view: Tuple[int, ...]      # (R, L) rows, or (P, S, L) slabs
+    tile: Tuple[int, ...]      # the view's block one grid step reads
+    group: int                 # rows (slabs) folded into one output row
+    per_value: int             # flat elements one output value covers
+    out_cols: int              # lanes of the output array
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _max_abs_delta(new: jnp.ndarray, old: jnp.ndarray, *,
-                   interpret: bool) -> jnp.ndarray:
-    nb, blk = new.shape
-    rt = min(ROW_TILE, nb)
-    ct = min(COL_TILE, blk)
-    # pad to tile multiples (padding contributes |0-0| = 0)
-    nb_p = -(-nb // rt) * rt
-    blk_p = -(-blk // ct) * ct
-    if (nb_p, blk_p) != (nb, blk):
-        new = jnp.pad(new, ((0, nb_p - nb), (0, blk_p - blk)))
-        old = jnp.pad(old, ((0, nb_p - nb), (0, blk_p - blk)))
-    out = pl.pallas_call(
-        _kernel,
-        out_shape=jax.ShapeDtypeStruct((nb_p, 1), jnp.float32),
-        grid=(nb_p // rt, blk_p // ct),
-        in_specs=[pl.BlockSpec((rt, ct), lambda ri, ci: (ri, ci)),
-                  pl.BlockSpec((rt, ct), lambda ri, ci: (ri, ci))],
-        out_specs=pl.BlockSpec((rt, 1), lambda ri, ci: (ri, 0)),
-        scratch_shapes=[pltpu.VMEM((rt, 1), jnp.float32)],
+def _fit(total: int, unit: int, unit_bytes: int) -> int:
+    """Rows of a tile: all ``total`` if they fit ``TILE_BYTES``, else the
+    largest multiple of ``unit`` that fits and divides ``total`` (or, when
+    none divides, that fits; the last tile is then ragged)."""
+    if total * unit_bytes <= TILE_BYTES:
+        return total
+    most = max(1, TILE_BYTES // (unit * unit_bytes))
+    if total % unit == 0:
+        n = total // unit
+        return unit * max(d for d in range(1, most + 1) if n % d == 0)
+    return unit * most
+
+
+def plan(shape: Tuple[int, ...], dtype, block: int) -> Optional[Plan]:
+    """How the kernel reads a leaf of ``shape`` in place, or None."""
+    if block % LANES or not shape or shape[-1] % LANES:
+        return None
+    L = shape[-1]
+    k, c = L // LANES, block // LANES
+    item = jnp.dtype(dtype).itemsize
+    sub = max(8, 32 // item)                  # rows of one sublane tile
+    if len(shape) > 2 and shape[-2] % sub:
+        P, S = math.prod(shape[:-2]), shape[-2]
+        if block % (S * L):
+            return None
+        tp = _fit(P, 1, S * L * item)
+        g = math.gcd(block // (S * L), tp)    # slabs a block holds
+        return Plan("slabs", (P, S, L), (tp, S, L), g, g * S * k, LANES)
+    R = math.prod(shape[:-1])
+    if c % k == 0:
+        ts = _fit(R, sub, L * item)
+        h = math.gcd(c // k, ts)              # rows a block holds
+        return Plan("rows", (R, L), (ts, L), h, h * k, LANES)
+    tc = min(k, SEGMENT_CHUNKS) * LANES
+    return Plan("segments", (R, L), (_fit(R, sub, tc * item), tc), 1,
+                LANES, min(k, LANES) * -(-k // LANES))
+
+
+def _kernel(new_ref, old_ref, out_ref, *, p: Plan, per_block: int):
+    rows, tile = p.view[0], p.tile
+    i = pl.program_id(0)
+
+    def diff(j):
+        """|new - old| of lane chunk ``j`` of the tile, in float32, with
+        the rows past the view's end (a ragged last tile) at 0."""
+        cols = slice(j * LANES, (j + 1) * LANES)
+        d = jnp.abs(new_ref[..., cols].astype(jnp.float32)
+                    - old_ref[..., cols].astype(jnp.float32))
+        if rows % tile[0]:
+            r = jax.lax.broadcasted_iota(jnp.int32, d.shape, 0)
+            d = jnp.where(r < rows - i * tile[0], d, 0.0)
+        return d
+
+    if p.mode == "segments":
+        # ``per_block`` column steps fill one output block, ``m`` lanes
+        # each; chunks past the row's end (a ragged last column tile)
+        # land in lanes the fold drops, or in none
+        m = tile[-1] // LANES
+        step = pl.program_id(1) % per_block
+        lane = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape[1:], 1)
+        acc = jnp.where(step == 0, 0.0, out_ref[0])
+        for j in range(m):
+            acc = jnp.where(lane == step * m + j,
+                            jnp.max(diff(j), axis=1, keepdims=True), acc)
+        out_ref[0] = acc
+        return
+    acc = diff(0)
+    for j in range(1, tile[-1] // LANES):
+        acc = jnp.maximum(acc, diff(j))
+    # ``group`` consecutive rows (or slabs, with their rows) fold into one
+    acc = acc.reshape(-1, p.group, *acc.shape[1:])
+    out_ref[0] = jnp.max(acc, axis=tuple(range(1, acc.ndim - 1)))
+
+
+@functools.partial(jax.jit, static_argnames=("p", "interpret"))
+def _partials(new: jnp.ndarray, old: jnp.ndarray, *, p: Plan,
+              interpret: bool) -> jnp.ndarray:
+    """The plan's float32 partial maxima, (grid steps, rows, lanes) in the
+    flat order of the data, as the kernel writes them."""
+    tile = p.tile
+    steps = -(-p.view[0] // tile[0])
+    cols = -(-p.view[-1] // tile[-1])         # > 1 for segments of long rows
+    width = min(p.out_cols, LANES)            # lanes of one output block
+    per_block = -(-width // (tile[-1] // LANES))   # column steps that fill it
+    out_rows = tile[0] // p.group
+    in_map = (lambda i, j: (i, j)) if len(tile) == 2 else \
+        (lambda i, j: (i, 0, j))
+    return pl.pallas_call(
+        functools.partial(_kernel, p=p, per_block=per_block),
+        out_shape=jax.ShapeDtypeStruct((steps, out_rows, p.out_cols),
+                                       jnp.float32),
+        grid=(steps, cols),
+        in_specs=[pl.BlockSpec(tile, in_map), pl.BlockSpec(tile, in_map)],
+        out_specs=pl.BlockSpec((1, out_rows, width),
+                               lambda i, j: (i, 0, j // per_block)),
         interpret=interpret,
         name="max_abs_delta",
-    )(new, old)
-    return out[:nb]
+    )(new.reshape(p.view), old.reshape(p.view))
 
 
-def max_abs_delta(new: jnp.ndarray, old: jnp.ndarray, *,
-                  interpret=None) -> jnp.ndarray:
-    """(n_blocks, block) x2 -> (n_blocks, 1) f32 max |new - old| per block.
+def block_max(new: jnp.ndarray, old: jnp.ndarray, block: int, *,
+              interpret=None) -> jnp.ndarray:
+    """Leaf pair (same shape and dtype, with a ``plan``) -> (n_blocks,)
+    float32 max |new - old| over each flat block of ``block`` elements,
+    the tail block padded with zeros. Traceable inside a jitted caller.
 
     ``interpret=None`` auto-detects: compiled on TPU, interpret mode
     (lowering validation) everywhere else.
     """
-    return _max_abs_delta(new, old,
-                          interpret=kb.resolve_interpret("tpu", interpret))
+    p = plan(new.shape, new.dtype, block)
+    q = _partials(new, old, p=p,
+                  interpret=kb.resolve_interpret("tpu", interpret))
+    if p.mode == "segments":                  # drop the ragged tile's lanes
+        q = q[..., :p.view[-1] // LANES]
+    per_block = block // p.per_value
+    nb = -(-new.size // block)
+    q = q.reshape(-1)[:nb * per_block]
+    q = jnp.pad(q, (0, nb * per_block - q.size))
+    return jnp.max(q.reshape(nb, per_block), axis=1)
+
+
+def max_abs_delta(new: jnp.ndarray, old: jnp.ndarray, *,
+                  interpret=None) -> jnp.ndarray:
+    """(n_blocks, block) x2 -> (n_blocks, 1) f32 max |new - old| per block;
+    a block that is no multiple of 128 is padded with zeros to one."""
+    blk = -(-new.shape[1] // LANES) * LANES
+    if blk != new.shape[1]:
+        pad = ((0, 0), (0, blk - new.shape[1]))
+        new, old = jnp.pad(new, pad), jnp.pad(old, pad)
+    return block_max(new, old, blk, interpret=interpret)[:, None]

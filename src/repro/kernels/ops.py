@@ -84,6 +84,22 @@ def dirty_blocks(new: jnp.ndarray, old: jnp.ndarray,
     return d[:, 0] > threshold
 
 
+def reads_in_place(leaf: jnp.ndarray, block: int) -> bool:
+    """Whether ``leaf_dirty_blocks`` can scan ``leaf``: a float leaf whose
+    shape and ``block`` the kernel reads through a bitcast view
+    (``dirty_delta.plan``)."""
+    return (jnp.issubdtype(leaf.dtype, jnp.floating)
+            and _dd.plan(leaf.shape, leaf.dtype, block) is not None)
+
+
+def leaf_dirty_blocks(new: jnp.ndarray, old: jnp.ndarray,
+                      block: int) -> jnp.ndarray:
+    """Leaf pair (any shape, ``reads_in_place``) -> (n_blocks,) bool dirty
+    mask over its flat blocks of ``block`` elements, the leaf read where
+    it lies: the same mask as ``dirty_blocks`` of the padded block view."""
+    return _dd.block_max(new, old, block) > 0
+
+
 # ---------------------------------------------------------------------------
 # DFT power spectrum (cycle recognition)
 # ---------------------------------------------------------------------------

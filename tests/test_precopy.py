@@ -185,3 +185,117 @@ def test_expected_cost_batch_matches_scalar_scan():
     scalar = [strunk.expected_cost(1e9, 125e6, tr.dirty_rate, start_time=s)
               for s in starts]
     np.testing.assert_array_equal(batch, scalar)
+
+
+# ---------------------------------------------------------------------------
+# the dirty scan reads each leaf in place: same masks as the block view
+# ---------------------------------------------------------------------------
+SCAN_BLOCK = 4096        # 32 lane chunks, as 16,384 is 128 on the replica
+
+#: the replica's leaf kinds at small sizes, by how their rows meet a block
+LEAF_SHAPES = {
+    "weight_3d": (2, 64, 256),           # wq/wk/.. : 16 rows a block
+    "weight_wide": (2, 32, 1024),        # w_gate/w_up: 4 rows a block
+    "embed_vd": (301, 512),              # (V, d), tail-padded
+    "head_dv": (16, 133 * 128),          # (d, V): blocks straddle rows,
+                                         # over two lane tiles
+    "head_dv_narrow": (64, 5 * 128),     # minor dim under one block
+    "norm_2d": (2, 256),                 # one tail-padded block
+    "final_norm_1d": (1152,),            # 1-D, tail-padded
+    "kv_cache": (2, 2, 16, 8, 128),      # second-minor 8
+}
+
+
+def _block_view_mask(new, old, block):
+    """The padded (n_blocks, block) view's max |new - old| > 0."""
+    from repro.kernels import ref
+    nb = -(-new.size // block)
+
+    def view(x):
+        return jnp.pad(x.reshape(-1), (0, nb * block - x.size)).reshape(
+            nb, block)
+
+    return np.asarray(ref.max_abs_delta_ref(view(new), view(old))[:, 0] > 0)
+
+
+def _changed_at(rng, shape, dtype, where):
+    size = int(np.prod(shape))
+    flat = {"block_first": SCAN_BLOCK, "block_last": 2 * SCAN_BLOCK - 1,
+            "before_tail": size - 1}[where] % size
+    new = jnp.asarray(rng.standard_normal(shape), dtype)
+    old = new.reshape(-1).at[flat].add(jnp.asarray(1, dtype)).reshape(shape)
+    return new, old, flat
+
+
+@pytest.mark.parametrize("where", ["block_first", "block_last",
+                                   "before_tail"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("kind", list(LEAF_SHAPES))
+def test_in_place_scan_masks_equal_block_view(kind, dtype, where):
+    shape = LEAF_SHAPES[kind]
+    new, old, flat = _changed_at(np.random.default_rng(3), shape, dtype,
+                                 where)
+    got = np.asarray(precopy._leaf_dirty(new, old, SCAN_BLOCK))
+    want = _block_view_mask(new, old, SCAN_BLOCK)
+    np.testing.assert_array_equal(got, want)
+    assert np.flatnonzero(got).tolist() == [flat // SCAN_BLOCK]
+
+
+def test_in_place_scan_int_scalar_stays_exact():
+    pos = jnp.asarray(2 ** 24 + 1, jnp.int32)     # aliases 2**24 in f32
+    got = precopy._leaf_dirty(pos, pos - 1, SCAN_BLOCK)
+    assert np.asarray(got).tolist() == [True]
+
+
+def _takes_view(leaf, block):
+    """The shape rule: a float leaf whose minor dim and block are whole
+    lane chunks, whose second-minor dim is a whole sublane tile (or whose
+    (second-minor, minor) slabs tile a block), is read in place."""
+    if not jnp.issubdtype(leaf.dtype, jnp.floating) or leaf.ndim == 0:
+        return False
+    if leaf.shape[-1] % 128 or block % 128:
+        return False
+    sublanes = max(8, 32 // leaf.dtype.itemsize)
+    return (leaf.ndim <= 2 or leaf.shape[-2] % sublanes == 0
+            or block % (leaf.shape[-2] * leaf.shape[-1]) == 0)
+
+
+@pytest.mark.parametrize("block", [SCAN_BLOCK, 1 << 14, 64])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_scan_counts_the_leaves_read_in_place(monkeypatch, dtype, block):
+    rng = np.random.default_rng(5)
+    state = {k: jnp.asarray(rng.standard_normal(s), dtype)
+             for k, s in LEAF_SHAPES.items()}
+    state["pos"] = jnp.asarray(7, jnp.int32)
+    state["odd"] = jnp.asarray(rng.standard_normal((4, 300)), dtype)
+    state["kv_unaligned"] = jnp.asarray(rng.standard_normal((3, 8, 384)),
+                                        dtype)
+    args = {}
+
+    class Recorder:
+        def __init__(self, name, **kw):
+            args.setdefault(name, {}).update(kw)
+            self.name = name
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+        def set_metadata(self, **kw):
+            args[self.name].update(kw)
+
+    monkeypatch.setattr(precopy, "span", Recorder)
+    monkeypatch.setattr(precopy, "enabled", lambda: True)
+    shadow = jax.tree.map(lambda x: x + 1, state)
+    masks, n_dirty, _ = precopy.dirty_scan(state, shadow, block)
+    leaves = jax.tree.leaves(state)
+    want = sum(_takes_view(leaf, block) for leaf in leaves)
+    assert args["precopy.scan"]["inplace"] == want
+    assert args["precopy.scan"]["syncs"] == len(leaves)
+    assert n_dirty == sum(-(-leaf.size // block) for leaf in leaves)
+    if block == SCAN_BLOCK:
+        assert want == len(leaves) - (3 if dtype == jnp.bfloat16 else 2)

@@ -9,6 +9,7 @@ refuses — tiling rules, unlowerable primitives, device memory — without a
 chip. The topology is described inside a fixture, never at import, so only
 the worker that runs this file loads the TPU compiler.
 """
+import math
 import os
 import re
 
@@ -16,9 +17,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import characterize
+from repro.core import characterize, precopy
 from repro.core import postpone as pp
-from repro.kernels import autocorr, dft, dirty_delta
+from repro.kernels import autocorr, backend, dft, dirty_delta
 
 #: device memory the blocked NB classify may take at the 100k-job bucket
 CLASSIFY_BYTES_MAX = 8e9
@@ -70,12 +71,45 @@ def test_autocorr_score_compiles(one_chip):
     assert _has_kernel(compiled, "autocorr_score")
 
 
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """Kernels that pick ``interpret`` from the running backend (the CPU
+    here) compile for the described chip instead."""
+    monkeypatch.setattr(backend, "resolve_interpret",
+                        lambda target, interpret: False)
+
+
+def _full_leaf_copies(compiled, size):
+    """Instructions of the compiled program that write an array as large
+    as a whole leaf: a copy, transpose, relayout reshape or fusion. Only
+    parameters and bitcasts (views) may be that large."""
+    ops = re.findall(r"= \w+\[([\d,]*)\]\S* ([\w-]+)\(",
+                     compiled.as_text())
+    return [(op, dims) for dims, op in ops
+            if op not in ("parameter", "bitcast")
+            and math.prod(int(d) for d in dims.split(",") if d) >= size]
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_max_abs_delta_compiles(one_chip, dtype):
     new = _spec((4096, 16384), dtype, one_chip)
-    compiled = dirty_delta._max_abs_delta.lower(new, new,
-                                                interpret=False).compile()
+    compiled = jax.jit(lambda n, o: dirty_delta.max_abs_delta(
+        n, o, interpret=False)).lower(new, new).compile()
     assert _has_kernel(compiled, "max_abs_delta")
+
+
+@pytest.mark.parametrize("shape", [(2048, 92544), (24, 4, 2048, 8, 128)],
+                         ids=["head", "kv_cache"])
+def test_leaf_dirty_reads_the_leaf_in_place(one_chip, compiled_kernels,
+                                            shape):
+    """The replica's LM head (blocks straddle its rows) and KV cache (8
+    heads, under bfloat16's 16-row tile) are scanned without a relayout."""
+    new = _spec(shape, jnp.bfloat16, one_chip)
+    compiled = precopy._leaf_dirty.lower(new, new, 16384).compile()
+    assert _has_kernel(compiled, "max_abs_delta")
+    assert _full_leaf_copies(compiled, math.prod(shape)) == []
+    leaf_bytes = 2 * math.prod(shape)
+    assert compiled.memory_analysis().temp_size_in_bytes < leaf_bytes / 64
 
 
 def test_nb_classify_fits_one_chip_at_100k_jobs(one_chip):
