@@ -22,6 +22,7 @@ ARCH_IDS = (
     "rwkv6_1p6b",
     "qwen3_moe_30b_a3b",
     "kimi_k2_1t_a32b",
+    "granite_4_0_h_micro",
 )
 
 _ALIASES = {
@@ -35,6 +36,7 @@ _ALIASES = {
     "rwkv6-1.6b": "rwkv6_1p6b",
     "qwen3-moe-30b-a3b": "qwen3_moe_30b_a3b",
     "kimi-k2-1t-a32b": "kimi_k2_1t_a32b",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 

@@ -70,11 +70,20 @@ class ArchConfig:
     mrope: bool = False                # qwen2-vl 3-axis M-RoPE
     mrope_sections: Tuple[int, int, int] = (16, 24, 24)
     sliding_window: int = 0            # 0 -> full attention (h2o-danube SWA)
+    use_rope: bool = True              # False -> no position embedding (NoPE)
+    attn_scale: float = 0.0            # softmax scale; 0 -> head_dim ** -0.5
+
+    # -- muP multipliers (granite): 1.0 leaves the graph as it was -------------
+    embedding_multiplier: float = 1.0  # x0 = m * embed[tok]
+    residual_multiplier: float = 1.0   # x + m * sublayer(x), both sublayers
+    logits_scaling: float = 1.0        # logits = head(x) / s
 
     # -- block wiring ---------------------------------------------------------
     # Repeating pattern of block kinds over depth. 'attn' = attention+MLP
     # block, 'moe' = attention+MoE block, 'mamba' = Mamba2 block,
-    # 'rwkv' = RWKV6 block, 'shared_attn' = zamba2 shared-weight attn block.
+    # 'rwkv' = RWKV6 block, 'shared_attn' = zamba2 shared-weight attn block,
+    # 'mamba_mlp' = Mamba2 mixer + MLP block (granite). A pattern mixing
+    # 'attn' and 'mamba_mlp' is wired interleaved (``models.lm``).
     block_pattern: Tuple[str, ...] = ("attn",)
     first_k_dense: int = 0             # kimi-k2: leading dense layers before MoE
 
@@ -157,13 +166,16 @@ class ArchConfig:
                     total += m.num_shared_experts * 3 * d * m.d_ff_expert
                 else:
                     total += 3 * d * self.d_ff                 # swiglu
-            elif kind == "mamba":
+            elif kind in ("mamba", "mamba_mlp"):
                 s = self.ssm
                 d_in = s.expand * d
                 nheads = d_in // s.head_dim
+                conv_ch = d_in + 2 * s.state_dim
                 total += d * (2 * d_in + 2 * s.state_dim + nheads)   # in_proj
-                total += s.conv_width * (d_in + 2 * s.state_dim)     # conv
-                total += d_in * d + 2 * nheads + d                   # out, A, D, norm
+                total += (s.conv_width + 1) * conv_ch                # conv w, b
+                total += d_in * d + 3 * nheads + d_in + d            # out, A, D, dt, norms
+                if kind == "mamba_mlp":
+                    total += 3 * d * self.d_ff + d                   # swiglu, norm
             elif kind == "rwkv":
                 total += 4 * d * d + 2 * d * s_lora(self.ssm)        # time-mix
                 total += d * self.d_ff + self.d_ff * d + d           # channel-mix

@@ -188,16 +188,18 @@ def migrate(get_state: Callable[[], Any],
                 rounds += 1
 
         # stop-and-copy: job paused; transfer the final dirty set
-        with span("precopy.stop_copy"):
+        with span("precopy.stop_copy") as sc:
             live = get_state()
             masks, n_dirty, n_bytes = dirty_scan(live, shadow,
                                                  cfg.block_elems)
             shadow = merge_dirty(live, shadow, masks, cfg.block_elems)
             shadow = jax.block_until_ready(shadow)
+            sent += n_bytes
+            if enabled():
+                sc.set_metadata(stop=reason, sent_bytes=sent)
         if enabled():
             s.set_metadata(rounds=rounds)
     downtime = n_bytes / cfg.bandwidth
-    sent += n_bytes
     sim_t += downtime
     per_round.append(n_bytes)
 

@@ -111,7 +111,8 @@ ATTN_CHUNK = 512
 
 
 def _chunked_causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                              window: int, chunk: int = ATTN_CHUNK) -> jnp.ndarray:
+                              window: int, chunk: int = ATTN_CHUNK,
+                              scale: float = 0.0) -> jnp.ndarray:
     """Memory-O(S·chunk) causal attention (online softmax over KV chunks).
 
     This is the XLA-path equivalent of the Pallas flash-attention kernel
@@ -120,10 +121,11 @@ def _chunked_causal_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     ``lax.scan`` over the causal KV range with running (m, l, acc). Each query
     chunk is rematerialized on backward so the S² probabilities never coexist.
 
-    q: (B, S, Hkv, G, hd); k, v: (B, S, Hkv, hd) -> (B, S, Hkv, G, hd)
+    q: (B, S, Hkv, G, hd); k, v: (B, S, Hkv, hd) -> (B, S, Hkv, G, hd).
+    ``scale`` 0 -> hd ** -0.5.
     """
     B, S, Hkv, G, hd = q.shape
-    scale = hd ** -0.5
+    scale = scale or hd ** -0.5
     if S <= chunk:
         logits = jnp.einsum("bqhgd,bkhd->bhgqk", q, k).astype(jnp.float32) * scale
         pos = jnp.arange(S)
@@ -198,16 +200,18 @@ def multihead_attention(
     if cfg.qk_norm:
         q = rmsnorm(params["q_norm"], q, cfg.norm_eps)
         k = rmsnorm(params["k_norm"], k, cfg.norm_eps)
-    q = apply_rope(q, angles)
-    k = apply_rope(k, angles)
-    scale = hd ** -0.5
+    if cfg.use_rope:
+        q = apply_rope(q, angles)
+        k = apply_rope(k, angles)
+    scale = cfg.attn_scale or hd ** -0.5
 
     if kv_cache is None:
         # ---- train / prefill: chunked causal (+SWA) attention ---------------
         g = H // Hkv
         qh = q.reshape(B, S, Hkv, g, hd)
         out = _chunked_causal_attention(qh, k, v, cfg.sliding_window,
-                                        chunk=min(cfg.attn_chunk, S))
+                                        chunk=min(cfg.attn_chunk, S),
+                                        scale=cfg.attn_scale)
         out = out.reshape(B, S, H * hd)
         new_cache = (k, v)
     else:

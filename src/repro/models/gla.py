@@ -1,7 +1,8 @@
-"""Chunked gated linear attention — the shared scan core for Mamba2 (SSD)
-and RWKV6 (Finch).
+"""Chunked gated linear attention — the scan core of RWKV6 (Finch), and in
+SSD mode of the ``ssm_scan`` kernel's oracle. (Mamba2 runs its own exact
+scan, ``models.mamba2.ssd_chunked``: it needs no decay clamp.)
 
-Both architectures are linear recurrences over an outer-product state::
+Both modes are linear recurrences over an outer-product state::
 
     S_t = diag(w_t) S_{t-1} + k_t v_t^T          # S: (Dk, Dv) per head
     y_t = q_t S_t            (+ bonus (q_t . u . k_t) v_t   for RWKV)

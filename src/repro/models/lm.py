@@ -7,6 +7,12 @@ Wiring modes (chosen from the config's block pattern):
 * ``hybrid_shared`` — zamba2: groups of Mamba2 layers with a *shared-weight*
                       attention block applied after each group.
 * ``prefix_dense``  — kimi-k2: a leading dense layer, then a scanned MoE stack.
+* ``interleaved``   — granite: layers of two kinds ('mamba_mlp', 'attn') in a
+                      per-layer order, each with its own weights. Each kind's
+                      layers are stacked in one pytree; each maximal run of
+                      same-kind layers is one ``lax.scan`` over its stretch of
+                      that stack, with a stacked cache of its own (a list per
+                      kind, in depth order), so HLO size is O(runs).
 
 Params are nested dicts; layer stacks are stacked pytrees scanned with
 ``jax.lax.scan`` so HLO size is O(1) in depth. ``remat='block'`` checkpoints
@@ -15,7 +21,7 @@ installed by the train-step builder (Megatron-style sequence sharding).
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +30,7 @@ from jax import lax
 from repro.configs.base import ArchConfig
 from repro.models import blocks as B
 from repro.models import mamba2, rwkv6
+from repro.spans import scope
 
 Params = Dict[str, Any]
 Batch = Dict[str, jnp.ndarray]
@@ -38,8 +45,33 @@ def wiring_mode(cfg: ArchConfig) -> str:
         return "hybrid_shared"
     if cfg.first_k_dense > 0:
         return "prefix_dense"
+    if set(cfg.block_pattern) == {"mamba_mlp", "attn"}:
+        return "interleaved"
     assert len(set(cfg.block_pattern)) == 1, cfg.block_pattern
     return "uniform"
+
+
+def _runs(cfg: ArchConfig) -> List[Tuple[str, int, int]]:
+    """interleaved: (kind, first index in the kind's stack, length) of each
+    maximal run of same-kind layers, in depth order."""
+    runs: List[Tuple[str, int, int]] = []
+    seen: Dict[str, int] = {}
+    for kind in cfg.pattern_for_depth():
+        if runs and runs[-1][0] == kind:
+            runs[-1] = (kind, runs[-1][1], runs[-1][2] + 1)
+        else:
+            runs.append((kind, seen.get(kind, 0), 1))
+        seen[kind] = seen.get(kind, 0) + 1
+    return runs
+
+
+#: device-side scope of each interleaved layer kind
+_SCOPE = {"mamba_mlp": "mamba", "attn": "attn"}
+
+
+def _layer(stack, i):
+    """Layer ``i`` (traced) of a stacked pytree."""
+    return jax.tree.map(lambda a: a[i], stack)
 
 
 def _group_shape(cfg: ArchConfig) -> Tuple[int, int]:
@@ -79,13 +111,29 @@ def _mamba_block_init(rng, cfg: ArchConfig) -> Params:
     }
 
 
+def _mamba_mlp_block_init(rng, cfg: ArchConfig) -> Params:
+    k1, k2 = jax.random.split(rng)
+    return {
+        "ln1": B.rmsnorm_init(cfg.d_model, cfg.dtype),
+        "mixer": mamba2.mamba2_init(k1, cfg),
+        "ln2": B.rmsnorm_init(cfg.d_model, cfg.dtype),
+        "mlp": B.mlp_init(k2, cfg),
+    }
+
+
 BLOCK_INIT = {
     "attn": _attn_block_init,
     "shared_attn": _attn_block_init,
     "moe": _moe_block_init,
     "mamba": _mamba_block_init,
+    "mamba_mlp": _mamba_mlp_block_init,
     "rwkv": rwkv6.rwkv6_init,
 }
+
+
+def _residual(cfg: ArchConfig, x: jnp.ndarray, h: jnp.ndarray) -> jnp.ndarray:
+    m = cfg.residual_multiplier
+    return x + h if m == 1.0 else x + m * h
 
 
 def apply_block(kind: str, params: Params, cfg: ArchConfig, x: jnp.ndarray,
@@ -98,21 +146,27 @@ def apply_block(kind: str, params: Params, cfg: ArchConfig, x: jnp.ndarray,
         h, new_kv = B.multihead_attention(
             params["attn"], cfg, B.rmsnorm(params["ln1"], x, cfg.norm_eps),
             angles, kv_cache=cache, cache_pos=cache_pos)
-        x = constrain(x + h)
+        x = constrain(_residual(cfg, x, h))
         h2 = B.rmsnorm(params["ln2"], x, cfg.norm_eps)
         if kind == "moe":
             mo, aux = B.moe_ffn(params["moe"], cfg, h2)
             x = constrain(x + mo)
         else:
-            x = constrain(x + B.mlp(params["mlp"], h2))
+            x = constrain(_residual(cfg, x, B.mlp(params["mlp"], h2)))
         return x, new_kv, aux
-    if kind == "mamba":
-        xn = B.rmsnorm(params["ln"], x, cfg.norm_eps)
+    if kind in ("mamba", "mamba_mlp"):
+        norm = params["ln" if kind == "mamba" else "ln1"]
+        xn = B.rmsnorm(norm, x, cfg.norm_eps)
         if cache is None:
             h, new_c = mamba2.mamba2_forward(params["mixer"], cfg, xn)
         else:
             h, new_c = mamba2.mamba2_decode(params["mixer"], cfg, xn, cache)
-        return constrain(x + h), new_c, aux
+        if kind == "mamba":
+            return constrain(x + h), new_c, aux
+        x = constrain(_residual(cfg, x, h))
+        h2 = B.rmsnorm(params["ln2"], x, cfg.norm_eps)
+        x = constrain(_residual(cfg, x, B.mlp(params["mlp"], h2)))
+        return x, new_c, aux
     if kind == "rwkv":
         x, new_c = rwkv6.rwkv6_block(params, cfg, x, cache)
         return constrain(x), new_c, aux
@@ -143,6 +197,11 @@ def init_params(cfg: ArchConfig, rng) -> Params:
     elif mode == "prefix_dense":
         p["dense0"] = _attn_block_init(k_extra, cfg)
         p["blocks"] = stacked("moe", cfg.num_layers - cfg.first_k_dense, k_blocks)
+    elif mode == "interleaved":
+        kinds = cfg.pattern_for_depth()
+        for i, kind in enumerate(dict.fromkeys(kinds)):
+            p[kind] = stacked(kind, kinds.count(kind),
+                              jax.random.fold_in(k_blocks, i))
     else:  # hybrid_shared
         n_groups, per = _group_shape(cfg)
         flat = stacked("mamba", n_groups * per, k_blocks)
@@ -173,8 +232,14 @@ def _positions(cfg: ArchConfig, batch: Batch, Bsz: int, S: int,
     return pos
 
 
+def _lookup(cfg: ArchConfig, params: Params, tokens: jnp.ndarray) -> jnp.ndarray:
+    x = jnp.take(params["embed"], tokens, axis=0)
+    m = cfg.embedding_multiplier
+    return x if m == 1.0 else x * m
+
+
 def _embed(cfg: ArchConfig, params: Params, batch: Batch) -> jnp.ndarray:
-    x = jnp.take(params["embed"], batch["tokens"], axis=0)
+    x = _lookup(cfg, params, batch["tokens"])
     if cfg.frontend_prefix and "prefix_embeds" in batch:
         pe = batch["prefix_embeds"].astype(x.dtype)       # (B, P, d) stub frontend
         x = lax.dynamic_update_slice(x, pe, (0, 0, 0))
@@ -185,7 +250,10 @@ def _head(cfg: ArchConfig, params: Params, x: jnp.ndarray,
           constrain_logits: Callable = Identity) -> jnp.ndarray:
     x = B.rmsnorm(params["final_ln"], x, cfg.norm_eps)
     w = params["embed"].T if cfg.tie_embeddings else params["head"]
-    return constrain_logits(x @ w)
+    logits = x @ w
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return constrain_logits(logits)
 
 
 # ---------------------------------------------------------------------------
@@ -206,7 +274,8 @@ def forward(params: Params, cfg: ArchConfig, batch: Batch, *,
     Bsz, S = batch["tokens"].shape
     x = constrain(_embed(cfg, params, batch))
     angles = (B.rope_angles(cfg, _positions(cfg, batch, Bsz, S))
-              if not cfg.attention_free else jnp.zeros((Bsz, S, 1)))
+              if not cfg.attention_free and cfg.use_rope
+              else jnp.zeros((Bsz, S, 1)))
     aux_total = jnp.zeros((), jnp.float32)
     cache = {"pos": jnp.asarray(S, jnp.int32)} if want_cache else None
 
@@ -253,6 +322,22 @@ def forward(params: Params, cfg: ArchConfig, batch: Batch, *,
             cache["dense0"] = _pack_cache(
                 "attn", jax.tree.map(lambda a: a[None], c0), ring_kv, kv_W)
             cache["moe"] = _pack_cache("moe", caches, ring_kv, kv_W)
+    elif mode == "interleaved":
+        for kind, lo, n in _runs(cfg):
+
+            def body(carry, i, kind=kind):
+                x, aux = carry
+                with scope(_SCOPE[kind]):
+                    x, c, a = apply_block(kind, _layer(params[kind], i), cfg,
+                                          x, angles, None, None, constrain)
+                return (x, aux + a), (c if want_cache else 0)
+
+            (x, aux_total), caches = lax.scan(
+                _maybe_remat(cfg, body), (x, aux_total),
+                jnp.arange(lo, lo + n))
+            if want_cache:
+                cache.setdefault(kind, []).append(
+                    _pack_cache(kind, caches, ring_kv, kv_W))
     else:  # hybrid_shared
         n_groups, per = _group_shape(cfg)
 
@@ -302,8 +387,8 @@ def decode_step(params: Params, cfg: ArchConfig, token: jnp.ndarray,
     mode = wiring_mode(cfg)
     Bsz = token.shape[0]
     pos = cache["pos"]
-    x = jnp.take(params["embed"], token, axis=0)
-    if not cfg.attention_free:
+    x = _lookup(cfg, params, token)
+    if not cfg.attention_free and cfg.use_rope:
         positions = jnp.broadcast_to(jnp.asarray(pos)[None, None], (Bsz, 1))
         if cfg.mrope:
             positions = jnp.broadcast_to(positions[None], (3, Bsz, 1))
@@ -338,6 +423,21 @@ def decode_step(params: Params, cfg: ArchConfig, token: jnp.ndarray,
 
         x, new_lc = lax.scan(body, x, (params["blocks"], cache["moe"]))
         new_cache["moe"] = new_lc
+    elif mode == "interleaved":
+        run_caches = {kind: iter(cache[kind]) for kind in _SCOPE}
+        for kind, lo, n in _runs(cfg):
+
+            def body(x, xs, kind=kind):
+                i, layer_cache = xs
+                with scope(_SCOPE[kind]):
+                    x, c, _ = apply_block(kind, _layer(params[kind], i), cfg,
+                                          x, angles, _unpack(kind, layer_cache),
+                                          pos, constrain)
+                return x, _repack(kind, c)
+
+            x, c = lax.scan(body, x, (jnp.arange(lo, lo + n),
+                                      next(run_caches[kind])))
+            new_cache.setdefault(kind, []).append(c)
     else:  # hybrid_shared
         n_groups, per = _group_shape(cfg)
         m_cache = jax.tree.map(
@@ -406,6 +506,11 @@ def init_cache(cfg: ArchConfig, batch: int, cache_len: int) -> Dict[str, Any]:
     elif mode == "prefix_dense":
         cache["dense0"] = kv(1)
         cache["moe"] = kv(cfg.num_layers - cfg.first_k_dense)
+    elif mode == "interleaved":
+        for kind, _, n in _runs(cfg):
+            cache.setdefault(kind, []).append(kv(n) if kind == "attn" else
+                jax.tree.map(lambda a: jnp.broadcast_to(a, (n, *a.shape)),
+                             mamba2.init_cache(cfg, batch, cfg.dtype)))
     else:
         n_groups, per = _group_shape(cfg)
         cache["mamba"] = jax.tree.map(

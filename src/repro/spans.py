@@ -4,9 +4,9 @@
 ``alma.<name>``. It writes to the profiler's host plane, on the clock of the
 device's ``XLA Ops``, so a trace shows what the host was doing while the
 chip waited. With no profiler session running it does nothing (about a
-microsecond per ``with``). Args must be numbers. A count known only when a
-span ends is added with ``set_metadata``, under ``enabled()``, so that
-tracing off builds no argument dicts::
+microsecond per ``with``). Args are numbers, or a short word such as a stop
+reason. A count known only when a span ends is added with ``set_metadata``,
+under ``enabled()``, so that tracing off builds no argument dicts::
 
     with span("precopy.scan", leaves=n) as s:
         ...

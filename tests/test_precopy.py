@@ -248,6 +248,30 @@ def test_in_place_scan_int_scalar_stays_exact():
     assert np.asarray(got).tolist() == [True]
 
 
+def _record_spans(monkeypatch) -> dict:
+    """Span name -> its args and metadata, from ``precopy``'s spans, as if
+    a profiler were recording."""
+    args = {}
+
+    class Recorder:
+        def __init__(self, name, **kw):
+            args.setdefault(name, {}).update(kw)
+            self.name = name
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+        def set_metadata(self, **kw):
+            args[self.name].update(kw)
+
+    monkeypatch.setattr(precopy, "span", Recorder)
+    monkeypatch.setattr(precopy, "enabled", lambda: True)
+    return args
+
+
 def _takes_view(leaf, block):
     """The shape rule: a float leaf whose minor dim and block are whole
     lane chunks, whose second-minor dim is a whole sublane tile (or whose
@@ -272,24 +296,7 @@ def test_scan_counts_the_leaves_read_in_place(monkeypatch, dtype, block):
     state["odd"] = jnp.asarray(rng.standard_normal((4, 300)), dtype)
     state["kv_unaligned"] = jnp.asarray(rng.standard_normal((3, 8, 384)),
                                         dtype)
-    args = {}
-
-    class Recorder:
-        def __init__(self, name, **kw):
-            args.setdefault(name, {}).update(kw)
-            self.name = name
-
-        def __enter__(self):
-            return self
-
-        def __exit__(self, *exc):
-            return False
-
-        def set_metadata(self, **kw):
-            args[self.name].update(kw)
-
-    monkeypatch.setattr(precopy, "span", Recorder)
-    monkeypatch.setattr(precopy, "enabled", lambda: True)
+    args = _record_spans(monkeypatch)
     shadow = jax.tree.map(lambda x: x + 1, state)
     masks, n_dirty, _ = precopy.dirty_scan(state, shadow, block)
     leaves = jax.tree.leaves(state)
@@ -299,3 +306,43 @@ def test_scan_counts_the_leaves_read_in_place(monkeypatch, dtype, block):
     assert n_dirty == sum(-(-leaf.size // block) for leaf in leaves)
     if block == SCAN_BLOCK:
         assert want == len(leaves) - (3 if dtype == jnp.bfloat16 else 2)
+
+
+def test_hybrid_replica_migrates_live_to_total_cap(monkeypatch):
+    """A tiny Mamba2 / attention replica (layers M, M, A, M) decodes a token
+    every round; each token rewrites every SSM state whole, so the bytes
+    sent end the migration, and the destination is the source leaf by
+    leaf."""
+    from repro.configs import get_config
+    from repro.models import lm
+    from repro.train import make_decode_step, make_prefill_step
+
+    M, A = "mamba_mlp", "attn"
+    cfg = get_config("granite_4_0_h_micro").smoke().replace(
+        num_layers=4, block_pattern=(M, M, A, M))
+    params = lm.init_params(cfg, jax.random.key(0))
+    tokens = jnp.asarray(np.random.default_rng(0).integers(
+        0, cfg.vocab_size, (8, 12)), jnp.int32)
+    _, cache = jax.jit(make_prefill_step(cfg, cache_len=64))(
+        params, {"tokens": tokens})
+    decode = jax.jit(make_decode_step(cfg))
+    box = {"params": params, "cache": cache, "tok": tokens[:, -1:]}
+
+    def step():
+        box["tok"], _, box["cache"] = decode(box["params"], box["tok"],
+                                             box["cache"])
+
+    def state():
+        return {"params": box["params"], "cache": box["cache"]}
+
+    args = _record_spans(monkeypatch)
+    cfg_pc = precopy.PrecopyConfig(block_elems=256, max_rounds=29,
+                                   stop_dirty_blocks=0)
+    dest, report = precopy.migrate(state, step, cfg_pc)
+    for a, b in zip(jax.tree.leaves(dest), jax.tree.leaves(state())):
+        assert jnp.array_equal(a, b)
+    assert report.outcome.stop_reason == "total_cap"
+    assert 1 < report.outcome.rounds < cfg_pc.max_rounds
+    assert args["precopy.migrate"]["rounds"] == report.outcome.rounds
+    assert args["precopy.stop_copy"] == {
+        "stop": "total_cap", "sent_bytes": report.outcome.bytes_sent}

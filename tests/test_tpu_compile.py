@@ -130,3 +130,25 @@ def test_postpone_batch_compiles(one_chip):
         _spec((131072,), jnp.int32, one_chip),
         _spec((131072,), jnp.int32, one_chip)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+
+
+def test_hybrid_serve_step_compiles(one_chip):
+    """The interleaved decode step (runs of Mamba2 layers and attention
+    layers, a cache of both kinds) at a small size, without a copy of its
+    cache: each run's cache goes through its scan layer by layer."""
+    from repro.configs import get_config
+    from repro.models import lm
+    from repro.train import make_decode_step
+    M, A = "mamba_mlp", "attn"
+    cfg = get_config("granite_4_0_h_micro").smoke().replace(
+        num_layers=4, block_pattern=(M, M, A, M))
+    as_spec = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: _spec(a.shape, a.dtype, one_chip), tree)
+    params = as_spec(jax.eval_shape(
+        lambda: lm.init_params(cfg, jax.random.key(0))))
+    cache = as_spec(jax.eval_shape(lambda: lm.init_cache(cfg, 16, 1024)))
+    compiled = jax.jit(make_decode_step(cfg)).lower(
+        params, _spec((16, 1), jnp.int32, one_chip), cache).compile()
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(cache))
+    assert compiled.memory_analysis().temp_size_in_bytes < cache_bytes / 4
