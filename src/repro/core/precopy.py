@@ -10,7 +10,9 @@ the moment of the final copy (tested in tests/test_precopy.py).
 
 Block diffing is the memory-bound hot loop -> Pallas kernel
 (``repro.kernels.dirty_delta``), which reads each float leaf and its shadow
-in place, in the leaf's own layout.
+in place, in the leaf's own layout. The merge is a Pallas kernel too
+(``repro.kernels.dirty_copy``): it writes only the dirty blocks, rounded
+out to boxes a DMA copies, into the donated shadow.
 
 Time accounting is dual: wall-clock (real copies) and a bandwidth model
 (bytes / link-bandwidth) so fleet-scale costs can be projected from smoke
@@ -22,15 +24,18 @@ from __future__ import annotations
 import dataclasses
 import time
 from dataclasses import dataclass
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.core.strunk import (MigrationOutcome, XEN_MAX_ROUNDS,
                                XEN_STOP_DIRTY_PAGES, XEN_STOP_TOTAL_FACTOR)
+from repro.kernels import dirty_copy
 from repro.kernels import ops as kops
 from repro.spans import enabled, scope, span
 
@@ -50,9 +55,9 @@ class PrecopyConfig:
 # ---------------------------------------------------------------------------
 def _blocks(leaf: jnp.ndarray, nb: int, block: int) -> jnp.ndarray:
     """(any shape) leaf -> (nb, block) zero-padded view of its flat data.
-    Traced inside the jitted scan/merge, so no flattened copy of the whole
-    state is ever materialized at once: that copy would double the state's
-    HBM footprint, beyond one chip for a 4.6 GB replica."""
+    Traced inside the jitted scan, so no flattened copy of the whole state
+    is ever materialized at once: that copy would double the state's HBM
+    footprint, beyond one chip for a 4.6 GB replica."""
     flat = leaf.reshape(-1)
     return jnp.pad(flat, (0, nb * block - flat.shape[0])).reshape(nb, block)
 
@@ -76,19 +81,63 @@ def _leaf_dirty(new: jnp.ndarray, old: jnp.ndarray, block: int) -> jnp.ndarray:
 def _leaf_merge(new: jnp.ndarray, old: jnp.ndarray, dirty: jnp.ndarray,
                 block: int) -> jnp.ndarray:
     """Copy dirty blocks of ``new`` over ``old`` (the 'network transfer').
-    ``old``'s buffer is donated to the result, so a round holds one shadow
-    copy of the state, not two."""
-    nb = dirty.shape[0]
+    ``old``'s buffer is donated to the result and only the boxes the dirty
+    blocks round out to are written into it, in place
+    (``kernels/dirty_copy.py``): a round holds one shadow copy of the
+    state, and a merge costs what is dirty, not what is held."""
     with scope("merge"):
-        out = jnp.where(dirty[:, None], _blocks(new, nb, block),
-                        _blocks(old.astype(new.dtype), nb, block))
-        return out.reshape(-1)[: new.size].reshape(old.shape).astype(
-            old.dtype)
+        return dirty_copy.copy_boxes(new.astype(old.dtype), old, dirty, block)
 
 
-def dirty_scan(live, shadow, block: int) -> Tuple[List[jnp.ndarray], int, int]:
-    """Per-leaf dirty masks + (dirty_blocks, dirty_bytes) totals."""
-    masks, n_dirty, n_bytes, syncs = [], 0, 0, 0
+def _leaf_merge_spread(new: jnp.ndarray, old: jnp.ndarray,
+                       dirty: jnp.ndarray, block: int) -> jnp.ndarray:
+    """``_leaf_merge`` of a leaf spread over several devices, which the
+    kernel cannot take: XLA writes the dirty blocks one by one into the
+    donated shadow (the last, partial, block as the leaf's last
+    ``block`` elements). The flat view it writes through is XLA's
+    relayout of the sharded leaf, so the bytes moved follow the leaf; the
+    result keeps ``old``'s sharding and buffers. A mesh's Explicit axes
+    refuse that flat view, so the leaf passes through the same placement
+    with every axis Auto (the same buffers, no copy)."""
+    dst = old.sharding
+    auto, flags = dst, dirty
+    if isinstance(dst, NamedSharding):
+        mesh = dst.mesh.update(
+            axis_types=(AxisType.Auto,) * len(dst.mesh.axis_names))
+        auto = NamedSharding(mesh, dst.spec)
+        flags = jax.device_put(dirty, NamedSharding(mesh, P()))
+    out = _spread_merge(auto, block)(
+        jax.device_put(new, auto), jax.device_put(old, auto, donate=True),
+        flags)
+    return jax.device_put(out, dst, donate=True)
+
+
+@lru_cache(maxsize=None)
+def _spread_merge(sharding, block: int):
+    """The jitted loop of ``_leaf_merge_spread``, its result pinned to
+    ``sharding`` so that the donated shadow's buffers take it."""
+
+    def merge(new, old, dirty):
+        with scope("merge"):
+            src = new.astype(old.dtype).reshape(-1)
+            n = min(block, src.shape[0])
+            at = jnp.nonzero(dirty, size=dirty.shape[0])[0] * block
+
+            def put(i, flat):
+                return lax.dynamic_update_slice(
+                    flat, lax.dynamic_slice(src, (at[i],), (n,)), (at[i],))
+
+            return lax.fori_loop(0, jnp.sum(dirty), put,
+                                 old.reshape(-1)).reshape(old.shape)
+
+    return jax.jit(merge, out_shardings=sharding, donate_argnums=(1,))
+
+
+def dirty_scan(live, shadow, block: int
+               ) -> Tuple[List[jnp.ndarray], int, int, List[int]]:
+    """Per-leaf dirty masks + (dirty_blocks, dirty_bytes) totals + each
+    leaf's dirty blocks."""
+    masks, counts, n_bytes, syncs = [], [], 0, 0
     leaves = jax.tree.leaves(live)
     with span("precopy.scan", leaves=len(leaves)) as s:
         for new, old in zip(leaves, jax.tree.leaves(shadow)):
@@ -96,18 +145,20 @@ def dirty_scan(live, shadow, block: int) -> Tuple[List[jnp.ndarray], int, int]:
             masks.append(m)
             d = int(jnp.sum(m))
             syncs += 1
-            n_dirty += d
+            counts.append(d)
             n_bytes += d * block * new.dtype.itemsize
         if enabled():
-            s.set_metadata(syncs=syncs, dirty_blocks=n_dirty,
+            s.set_metadata(syncs=syncs, dirty_blocks=sum(counts),
                            inplace=sum(kops.reads_in_place(leaf, block)
                                        for leaf in leaves))
-    return masks, n_dirty, n_bytes
+    return masks, sum(counts), n_bytes, counts
 
 
-def merge_dirty(live, shadow, masks: List[jnp.ndarray], block: int):
+def merge_dirty(live, shadow, masks: List[jnp.ndarray], block: int,
+                counts: Optional[List[int]] = None):
     """Shadow with every dirty block of ``live`` copied over it. Consumes
-    ``shadow``: its leaves are donated to the result."""
+    ``shadow``: its leaves are donated to the result. ``counts``, each
+    leaf's dirty blocks, give the span's ``copied_bytes`` when tracing."""
 
     def align(n, o):
         """The cross-placement transfer: move live data onto the destination
@@ -116,10 +167,18 @@ def merge_dirty(live, shadow, masks: List[jnp.ndarray], block: int):
             n = jax.device_put(n, o.sharding)
         return n
 
+    def merge(o):
+        spread = len(o.sharding.device_set) > 1
+        return _leaf_merge_spread if spread else _leaf_merge
+
     leaves = jax.tree.leaves(shadow)
-    with span("precopy.merge", leaves=len(leaves)):
-        merged = [_leaf_merge(align(n, o), o, m, block)
+    with span("precopy.merge", leaves=len(leaves)) as s:
+        merged = [merge(o)(align(n, o), o, m, block)
                   for n, o, m in zip(jax.tree.leaves(live), leaves, masks)]
+        if enabled() and counts is not None:
+            s.set_metadata(copied_bytes=sum(
+                dirty_copy.copied_bytes(o.shape, o.dtype, block, d)
+                for o, d in zip(leaves, counts)))
     return jax.tree.unflatten(jax.tree.structure(shadow), merged)
 
 
@@ -170,8 +229,8 @@ def migrate(get_state: Callable[[], Any],
                     for _ in range(cfg.steps_per_round):
                         step_fn()
                 live = get_state()
-                masks, n_dirty, n_bytes = dirty_scan(live, shadow,
-                                                     cfg.block_elems)
+                masks, n_dirty, n_bytes, counts = dirty_scan(
+                    live, shadow, cfg.block_elems)
                 if n_dirty <= cfg.stop_dirty_blocks:
                     reason = "dirty_low"
                     break
@@ -181,7 +240,8 @@ def migrate(get_state: Callable[[], Any],
                 if sent + n_bytes > cfg.stop_total_factor * v_mem:
                     reason = "total_cap"
                     break
-                shadow = merge_dirty(live, shadow, masks, cfg.block_elems)
+                shadow = merge_dirty(live, shadow, masks, cfg.block_elems,
+                                     counts)
                 sent += n_bytes
                 sim_t += n_bytes / cfg.bandwidth
                 per_round.append(n_bytes)
@@ -190,9 +250,10 @@ def migrate(get_state: Callable[[], Any],
         # stop-and-copy: job paused; transfer the final dirty set
         with span("precopy.stop_copy") as sc:
             live = get_state()
-            masks, n_dirty, n_bytes = dirty_scan(live, shadow,
-                                                 cfg.block_elems)
-            shadow = merge_dirty(live, shadow, masks, cfg.block_elems)
+            masks, n_dirty, n_bytes, counts = dirty_scan(
+                live, shadow, cfg.block_elems)
+            shadow = merge_dirty(live, shadow, masks, cfg.block_elems,
+                                 counts)
             shadow = jax.block_until_ready(shadow)
             sent += n_bytes
             if enabled():

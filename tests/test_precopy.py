@@ -4,6 +4,10 @@ The central correctness property: after stop-and-copy the destination pytree
 equals the source **exactly**, no matter how the job mutated state between
 rounds. Plus the Xen stop conditions and the Strunk analytic bounds
 (hypothesis)."""
+import subprocess
+import sys
+import textwrap
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -298,7 +302,7 @@ def test_scan_counts_the_leaves_read_in_place(monkeypatch, dtype, block):
                                         dtype)
     args = _record_spans(monkeypatch)
     shadow = jax.tree.map(lambda x: x + 1, state)
-    masks, n_dirty, _ = precopy.dirty_scan(state, shadow, block)
+    masks, n_dirty, _, _ = precopy.dirty_scan(state, shadow, block)
     leaves = jax.tree.leaves(state)
     want = sum(_takes_view(leaf, block) for leaf in leaves)
     assert args["precopy.scan"]["inplace"] == want
@@ -346,3 +350,288 @@ def test_hybrid_replica_migrates_live_to_total_cap(monkeypatch):
     assert args["precopy.migrate"]["rounds"] == report.outcome.rounds
     assert args["precopy.stop_copy"] == {
         "stop": "total_cap", "sent_bytes": report.outcome.bytes_sent}
+
+
+# ---------------------------------------------------------------------------
+# the merge writes only the dirty boxes, in place: the same shadow as the
+# padded block view's where
+# ---------------------------------------------------------------------------
+#: the cells' leaf kinds at small sizes: (shape, dtype, block, the dims
+#: major to minor and the tile rows a v5e lays the cell's leaf out in)
+MERGE_LEAVES = {
+    "kv_bf16": ((2, 2, 64, 8, 128), jnp.bfloat16, 4096,
+                ((0, 1, 2, 3, 4), 8)),
+    "ssm_f32": ((2, 2, 4, 128, 64), jnp.float32, 1 << 14,
+                ((0, 1, 2, 4, 3), 8)),
+    "kv_head64": ((1, 4, 256, 8, 64), jnp.bfloat16, 1 << 14,
+                  ((0, 1, 3, 4, 2), 8)),
+    "in_proj_8512": ((1, 256, 8512), jnp.bfloat16, 1 << 14,
+                     ((0, 2, 1), 8)),
+    "vocab_rows": ((16, 133 * 128), jnp.bfloat16, 4096, ((0, 1), 8)),
+    "conv_window": ((2, 16, 3, 4352), jnp.bfloat16, 1 << 14,
+                    ((0, 2, 1, 3), 8)),
+    "conv_ragged": ((2, 4, 3, 200), jnp.bfloat16, 4096,
+                    ((0, 2, 1, 3), 8)),
+    "norm_vector": ((2048,), jnp.bfloat16, 4096, ((0,), 8)),
+    "int_scalar": ((), jnp.int32, 4096, ((), 8)),
+}
+MERGE_PATTERNS = ["none", "one", "last", "scattered", "all"]
+
+
+def _padded_where(new, old, dirty, block):
+    """The merge of the padded ``(n_blocks, block)`` view: a full-leaf
+    ``where`` of the dirty blocks of ``new`` over ``old``."""
+    nb = dirty.shape[0]
+
+    def view(x):
+        return jnp.pad(x.reshape(-1), (0, nb * block - x.size)).reshape(
+            nb, block)
+
+    out = jnp.where(dirty[:, None], view(new), view(old))
+    return out.reshape(-1)[:new.size].reshape(old.shape)
+
+
+def _dirty_pair(kind, pattern, seed=7):
+    """(live leaf, shadow that differs from it in the dirty blocks only,
+    mask, block)."""
+    shape, dtype, block, _ = MERGE_LEAVES[kind]
+    size = max(1, int(np.prod(shape)))
+    nb = -(-size // block)
+    dirty = np.zeros(nb, bool)
+    if pattern == "one":
+        dirty[nb // 2] = True
+    elif pattern == "last":
+        dirty[-1] = True
+    elif pattern == "scattered":
+        dirty[::3] = True
+        dirty[min(1, nb - 1)] = True
+    elif pattern == "all":
+        dirty[:] = True
+    rng = np.random.default_rng(seed)
+    if jnp.issubdtype(dtype, jnp.floating):
+        new = jnp.asarray(rng.standard_normal(shape), dtype)
+    else:
+        new = jnp.asarray(rng.integers(-2 ** 30, 2 ** 30, shape), dtype)
+    hit = jnp.asarray(np.repeat(dirty, block)[:size].reshape(shape))
+    old = jnp.where(hit, new + jnp.asarray(1, dtype), new)
+    return new, old, jnp.asarray(dirty), block
+
+
+@pytest.mark.parametrize("pattern", MERGE_PATTERNS)
+@pytest.mark.parametrize("layout", ["row_major", "v5e"])
+@pytest.mark.parametrize("kind", list(MERGE_LEAVES))
+def test_merge_equals_the_padded_where(monkeypatch, kind, layout, pattern):
+    """Bit for bit, in the layout the host gives (row-major) and in the
+    one a v5e gives the cell's leaf, boxes and rounding included."""
+    from repro.kernels import dirty_copy
+    new, old, dirty, block = _dirty_pair(kind, pattern)
+    want = np.asarray(_padded_where(new, old, dirty, block))
+    merge = precopy._leaf_merge
+    if layout == "v5e":
+        order = MERGE_LEAVES[kind][3]
+        monkeypatch.setattr(dirty_copy, "layout", lambda s, d: order)
+        # a jit of its own: the module's caches the host's layout
+        merge = jax.jit(precopy._leaf_merge.__wrapped__, static_argnums=3,
+                        donate_argnums=1)
+    got = merge(new, old, dirty, block)
+    assert got.dtype == new.dtype and got.shape == new.shape
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert old.is_deleted()                  # the shadow was donated
+
+
+WAITS_SCRIPT = textwrap.dedent("""
+    import sys
+    sys.path.insert(0, sys.argv[1])
+    import numpy as np
+    from jax.experimental.pallas import tpu as pltpu
+    from repro.kernels import dirty_copy
+    import test_precopy as t
+
+    for kind in ("kv_bf16", "kv_head64", "ssm_f32", "in_proj_8512",
+                 "vocab_rows"):
+        order = t.MERGE_LEAVES[kind][3]
+        dirty_copy.layout = lambda s, d, o=order: o
+        for pattern in ("last", "scattered", "all"):
+            new, old, dirty, block = t._dirty_pair(kind, pattern)
+            want = np.asarray(t._padded_where(new, old, dirty, block))
+            got = dirty_copy.copy_boxes(new, old, dirty, block,
+                                        interpret=pltpu.InterpretParams())
+            np.testing.assert_array_equal(np.asarray(got), want)
+            p = dirty_copy.plan(new.shape, new.dtype, block)
+            print("MERGED", kind, pattern, p.lanes, p.view[0], flush=True)
+""")
+
+
+def test_merge_kernel_waits_for_the_bytes_it_starts():
+    """The TPU interpreter counts a DMA semaphore in bytes: every copy a
+    run starts is waited for, class by class, with a wait of its own size
+    (a wait of the wrong size blocks, so the script runs under a time
+    limit; one too few leaves a count at the kernel's exit). Runs that end
+    at the last unit, 128-lane columns (``lanes`` 2) and runs long enough
+    for three copy sizes (1,064 units) are covered."""
+    import os
+    r = subprocess.run([sys.executable, "-c", WAITS_SCRIPT,
+                        os.path.dirname(__file__)],
+                       capture_output=True, text=True, timeout=240)
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "non-zero count" not in r.stdout + r.stderr
+    merged = [l.split()[1:] for l in r.stdout.splitlines()
+              if l.startswith("MERGED")]
+    assert len(merged) == 15
+    assert {m[2] for m in merged} == {"1", "2"}            # lanes
+    assert max(int(m[3]) for m in merged) >= 16 ** 2       # three classes
+
+
+def test_merge_plans_copy_boxes_of_the_cells_leaves(monkeypatch):
+    """In a v5e's layout every cell leaf kind but the tiny and ragged ones
+    is copied box by box, and a dirty block's box is the block itself
+    where the block is whole slabs or rows."""
+    from repro.kernels import dirty_copy
+    boxes = {}
+    for kind, (shape, dtype, block, order) in MERGE_LEAVES.items():
+        monkeypatch.setattr(dirty_copy, "layout", lambda s, d, o=order: o)
+        p = dirty_copy.plan(shape, dtype, block)
+        boxes[kind] = (p.view is not None, p.lanes, p.box_elems)
+    assert boxes["kv_bf16"] == (True, 1, 4096)
+    assert boxes["ssm_f32"] == (True, 1, 1 << 14)
+    assert boxes["kv_head64"] == (True, 2, 64 * 8 * 128)   # a lane tile
+    assert boxes["in_proj_8512"][:2] == (True, 2)
+    assert not boxes["conv_ragged"][0] and not boxes["norm_vector"][0]
+    assert not boxes["int_scalar"][0]
+
+
+def test_migration_of_every_leaf_kind_is_exact():
+    """A state of every kind, stepped between rounds, migrates to a
+    destination equal to its source, leaf by leaf."""
+    state = {k: _dirty_pair(k, "none")[0] for k in MERGE_LEAVES}
+    calls = {"n": 0}
+
+    def step():
+        calls["n"] += 1
+        for k in ("kv_bf16", "ssm_f32", "int_scalar"):
+            x = state[k]
+            state[k] = x.reshape(-1).at[calls["n"] * 997 % x.size].add(
+                jnp.asarray(1, x.dtype)).reshape(x.shape)
+
+    cfg = precopy.PrecopyConfig(block_elems=4096, max_rounds=4,
+                                stop_dirty_blocks=0)
+    dest, report = precopy.migrate(lambda: dict(state), step, cfg)
+    assert calls["n"] == report.outcome.rounds
+    for k in state:
+        assert jnp.array_equal(dest[k], state[k]), k
+
+
+def test_spread_merge_equals_the_padded_where():
+    """The XLA path for a leaf spread over several devices writes the same
+    shadow (run here on one device)."""
+    for kind in ("kv_bf16", "vocab_rows", "int_scalar"):
+        for pattern in ("one", "last", "scattered"):
+            new, old, dirty, block = _dirty_pair(kind, pattern)
+            want = np.asarray(_padded_where(new, old, dirty, block))
+            got = precopy._leaf_merge_spread(new, old, dirty, block)
+            np.testing.assert_array_equal(np.asarray(got), want)
+
+
+SPREAD_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+    from repro.core import precopy
+
+    def padded_where(new, old, dirty, block):
+        nb = dirty.shape[0]
+        view = lambda x: np.pad(x.reshape(-1), (0, nb * block - x.size)
+                                ).reshape(nb, block)
+        out = np.where(dirty[:, None], view(new), view(old))
+        return out.reshape(-1)[:new.size].reshape(old.shape)
+
+    rng = np.random.default_rng(3)
+    for kind in (AxisType.Auto, AxisType.Explicit):
+        mesh = jax.make_mesh((2,), ("x",), axis_types=(kind,))
+        for shape, spec, block in (((4, 64, 128), P("x"), 4096),
+                                   ((16, 133 * 128), P(None, "x"), 4096),
+                                   ((6, 200), P("x"), 256)):
+            nb = -(-int(np.prod(shape)) // block)
+            dirty = np.zeros(nb, bool)
+            dirty[::3] = dirty[-1] = True
+            new = rng.standard_normal(shape).astype(np.float32)
+            hit = np.repeat(dirty, block)[:new.size].reshape(shape)
+            old = np.where(hit, new + 1, new)    # equal where clean
+            want = padded_where(new, old, dirty, block)
+            sharding = NamedSharding(mesh, spec)
+            live = jax.device_put(new, sharding)
+            # a buffer of the device's own (one put from numpy may alias
+            # the host array, and then a donation copies)
+            shadow = jax.device_put(old, sharding) * 1
+            placed = shadow.sharding
+            assert placed.is_equivalent_to(sharding, len(shape))
+            buffers = [s.data.unsafe_buffer_pointer()
+                       for s in shadow.addressable_shards]
+            got, = precopy.merge_dirty([live], [shadow],
+                                       [jnp.asarray(dirty)], block)
+            np.testing.assert_array_equal(np.asarray(got), want)
+            assert got.sharding == placed, (got.sharding, placed)
+            assert shadow.is_deleted()
+            assert [s.data.unsafe_buffer_pointer()
+                    for s in got.addressable_shards] == buffers
+    print("SPREAD_MERGE_OK")
+""")
+
+
+def test_spread_merge_keeps_the_sharding_and_buffers():
+    """On two devices, under an Auto and an Explicit mesh, a leaf sharded
+    along its leading or its minor dim merges to the padded ``where``'s
+    values, keeps its sharding, and takes the donated shadow's buffers
+    (a subprocess: the test process keeps one device)."""
+    r = subprocess.run([sys.executable, "-c", SPREAD_SCRIPT],
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "SPREAD_MERGE_OK" in r.stdout
+
+
+def _merge_state(seed=11):
+    return {k: _dirty_pair(k, "scattered", seed)[:2]
+            for k in ("kv_bf16", "vocab_rows", "conv_ragged", "norm_vector",
+                      "int_scalar")}
+
+
+def test_merge_span_carries_copied_bytes(monkeypatch):
+    """Traced, the merge span's ``copied_bytes`` holds at least the bytes
+    of the round's dirty blocks (within each leaf), and 0 on a round with
+    nothing dirty; the count adds no host-device transfer."""
+    pairs = _merge_state()
+    live = {k: p[0] for k, p in pairs.items()}
+    shadow = {k: p[1] for k, p in pairs.items()}
+    args = _record_spans(monkeypatch)
+    block = 4096
+    masks, n_dirty, _, counts = precopy.dirty_scan(live, shadow, block)
+    dirty_bytes = sum(min(d * block, max(1, leaf.size)) * leaf.dtype.itemsize
+                      for d, leaf in zip(counts, jax.tree.leaves(live)))
+    assert n_dirty > 0
+    with jax.transfer_guard_device_to_host("disallow"):
+        merged = precopy.merge_dirty(live, shadow, masks, block, counts)
+    assert args["precopy.merge"]["copied_bytes"] >= dirty_bytes
+    masks, n_dirty, _, counts = precopy.dirty_scan(live, merged, block)
+    assert n_dirty == 0
+    precopy.merge_dirty(live, merged, masks, block, counts)
+    assert args["precopy.merge"]["copied_bytes"] == 0
+
+
+def test_merge_span_untraced_sets_nothing(monkeypatch):
+    """Without a profiler session the merge computes no count and sets no
+    arg."""
+    from repro.kernels import dirty_copy
+
+    def counted(*a):
+        raise AssertionError("counted without a profiler session")
+
+    monkeypatch.setattr(dirty_copy, "copied_bytes", counted)
+    pairs = _merge_state()
+    live = {k: p[0] for k, p in pairs.items()}
+    shadow = {k: p[1] for k, p in pairs.items()}
+    masks, _, _, counts = precopy.dirty_scan(live, shadow, 4096)
+    with jax.transfer_guard_device_to_host("disallow"):
+        merged = precopy.merge_dirty(live, shadow, masks, 4096, counts)
+    for k in live:
+        assert jnp.array_equal(merged[k], live[k])

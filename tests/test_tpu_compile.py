@@ -112,6 +112,47 @@ def test_leaf_dirty_reads_the_leaf_in_place(one_chip, compiled_kernels,
     assert compiled.memory_analysis().temp_size_in_bytes < leaf_bytes / 64
 
 
+@pytest.fixture
+def v5e_layouts(monkeypatch, one_chip):
+    """The merge plans its boxes in the layouts the described chip gives,
+    not the host's."""
+    from jax.experimental.layout import Layout
+    from repro.kernels import dirty_copy
+    dev = next(iter(one_chip.device_set))
+
+    def layout(shape, dtype):
+        got = Layout.from_pjrt_layout(dev.client.get_default_layout(
+            jnp.dtype(dtype), tuple(shape), dev))
+        tiles = [t for t in got.tiling if len(t) == 2]
+        return tuple(got.major_to_minor), tiles[0][0] if tiles else 8
+
+    monkeypatch.setattr(dirty_copy, "layout", layout)
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((24, 4, 2048, 8, 128), jnp.bfloat16), ((2048, 92544), jnp.bfloat16),
+    ((9, 16, 64, 128, 64), jnp.float32), ((1, 16, 8192, 8, 64), jnp.bfloat16),
+    ((18, 2048, 8512), jnp.bfloat16)],
+    ids=["kv_cache", "head", "hybrid_ssm", "hybrid_kv", "in_proj"])
+def test_leaf_merge_writes_the_shadow_in_place(one_chip, compiled_kernels,
+                                               v5e_layouts, shape, dtype):
+    """The cells' largest and oddest leaves merge by the kernel alone:
+    no instruction but the kernel writes a whole-leaf array, the result
+    aliases the donated shadow, and the temp stays under 1/1024 of the
+    leaf, so no padded or full copy can come back."""
+    blocks = -(-math.prod(shape) // 16384)
+    leaf = _spec(shape, dtype, one_chip)
+    compiled = precopy._leaf_merge.lower(
+        leaf, leaf, _spec((blocks,), jnp.bool_, one_chip), 16384).compile()
+    assert _has_kernel(compiled, "copy_dirty_boxes")
+    assert [op for op, _ in _full_leaf_copies(compiled, math.prod(shape))
+            if op != "custom-call"] == []
+    leaf_bytes = math.prod(shape) * jnp.dtype(dtype).itemsize
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= leaf_bytes
+    assert mem.temp_size_in_bytes < leaf_bytes / 1024
+
+
 def test_nb_classify_fits_one_chip_at_100k_jobs(one_chip):
     f32 = jnp.float32
     args = (_spec((6, 15), f32, one_chip), _spec((4, 6, 16), f32, one_chip),
