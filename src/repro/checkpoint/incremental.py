@@ -22,7 +22,17 @@ import numpy as np
 from repro.checkpoint.store import (CODEC, compress_bytes, decompress_bytes,
                                     restore_checkpoint, save_checkpoint,
                                     _flatten_with_paths)
-from repro.core.precopy import _leaf_dirty
+from repro.kernels import dirty_delta
+
+#: the pre-copy's dirty-block mask, one compiled program per leaf size
+_dirty_mask = jax.jit(dirty_delta.dirty_mask, static_argnums=2)
+
+
+def _bytes(a: np.ndarray) -> jnp.ndarray:
+    """Flat host array -> its bytes on the device, so that the mask
+    compares bits: exact for NaN, inf and subnormal floats, and for 64-bit
+    integers, which JAX would otherwise narrow to 32."""
+    return jnp.asarray(np.ascontiguousarray(a).view(np.uint8))
 
 
 class IncrementalCheckpointer:
@@ -55,14 +65,8 @@ class IncrementalCheckpointer:
             nv = new.reshape(-1)
             ov = old.reshape(-1).astype(nv.dtype)
             nb = -(-nv.size // self.block)
-            if np.issubdtype(nv.dtype, np.floating):
-                dirty = np.asarray(_leaf_dirty(jnp.asarray(nv),
-                                               jnp.asarray(ov), self.block))
-            else:
-                pad = nb * self.block - nv.size
-                dirty = np.any(np.pad(nv, (0, pad)).reshape(nb, self.block)
-                               != np.pad(ov, (0, pad)).reshape(nb, self.block),
-                               axis=1)
+            dirty = np.asarray(_dirty_mask(_bytes(nv), _bytes(ov),
+                                           self.block * nv.itemsize))
             idx = np.flatnonzero(dirty)
             if idx.size == 0:
                 continue

@@ -48,9 +48,8 @@ class CycleModel:
 
 def _resolve_kernel(use_kernel: Optional[bool]) -> bool:
     # interpret-mode Pallas is for lowering validation, not CPU throughput:
-    # off-accelerator (no TPU or GPU kernel row) the default is the
-    # pocketfft/numpy path.
-    return kops.has_accelerator() if use_kernel is None else use_kernel
+    # off the TPU the default is the pocketfft/numpy path.
+    return kops.on_tpu() if use_kernel is None else use_kernel
 
 
 def _warn_host_fallback(stage: str, n: int) -> None:
@@ -170,7 +169,7 @@ def _refine_period_batch(X: np.ndarray, p0: np.ndarray, min_period: int,
             _warn_host_fallback("period refinement", n)
             kernel = False
         if kernel:
-            # Pallas kernel (TPU or GPU row of the dispatch table): fleet x
+            # Pallas kernel (TPU row of the dispatch table): fleet x
             # shared candidate-lag grid in one call, optionally row-sharded
             import jax.numpy as jnp
             lags = np.arange(lag_lo, lag_hi + 1)
@@ -181,7 +180,7 @@ def _refine_period_batch(X: np.ndarray, p0: np.ndarray, min_period: int,
                 R = np.asarray(scores)
             R = R.astype(np.float64)
         else:
-            # off-accelerator: Wiener-Khinchin on the zero-padded rows
+            # off the TPU: Wiener-Khinchin on the zero-padded rows
             # gives the exact linear autocorrelation R[j, p] = sum_t x[t]
             # x[t+p] at EVERY lag in one vectorized pocketfft pass
             # (interpret-mode Pallas is not a CPU hot path)

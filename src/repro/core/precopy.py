@@ -35,8 +35,7 @@ from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.core.strunk import (MigrationOutcome, XEN_MAX_ROUNDS,
                                XEN_STOP_DIRTY_PAGES, XEN_STOP_TOTAL_FACTOR)
-from repro.kernels import dirty_copy
-from repro.kernels import ops as kops
+from repro.kernels import dirty_copy, dirty_delta
 from repro.spans import enabled, scope, span
 
 
@@ -50,31 +49,13 @@ class PrecopyConfig:
     steps_per_round: int = 1                   # job steps overlapped per round
 
 
-# ---------------------------------------------------------------------------
-# flat block view of a pytree
-# ---------------------------------------------------------------------------
-def _blocks(leaf: jnp.ndarray, nb: int, block: int) -> jnp.ndarray:
-    """(any shape) leaf -> (nb, block) zero-padded view of its flat data.
-    Traced inside the jitted scan, so no flattened copy of the whole state
-    is ever materialized at once: that copy would double the state's HBM
-    footprint, beyond one chip for a 4.6 GB replica."""
-    flat = leaf.reshape(-1)
-    return jnp.pad(flat, (0, nb * block - flat.shape[0])).reshape(nb, block)
-
-
 @partial(jax.jit, static_argnums=(2,))
 def _leaf_dirty(new: jnp.ndarray, old: jnp.ndarray, block: int) -> jnp.ndarray:
-    """Leaf pair (same shape) -> (nb,) bool dirty mask over its flat blocks.
-    A leaf the kernel can view as a bitcast of itself (``kops.
-    reads_in_place``) is read where it lies; any other, and every integer
-    leaf (compared exactly), through the padded block view."""
+    """Leaf pair (same shape) -> (nb,) bool dirty mask over its flat blocks
+    (``kernels/dirty_delta.py``): a float leaf the kernel can view as a
+    bitcast of itself is read where it lies."""
     with scope("dirty_scan"):
-        old = old.astype(new.dtype)
-        if kops.reads_in_place(new, block):
-            return kops.leaf_dirty_blocks(new, old, block)
-        nb = -(-new.size // block)
-        return kops.dirty_blocks(_blocks(new, nb, block),
-                                 _blocks(old, nb, block))
+        return dirty_delta.dirty_mask(new, old.astype(new.dtype), block)
 
 
 @partial(jax.jit, static_argnums=(3,), donate_argnums=(1,))
@@ -148,9 +129,10 @@ def dirty_scan(live, shadow, block: int
             counts.append(d)
             n_bytes += d * block * new.dtype.itemsize
         if enabled():
+            inplace = sum(dirty_delta.reads_in_place(leaf, block)
+                          for leaf in leaves)
             s.set_metadata(syncs=syncs, dirty_blocks=sum(counts),
-                           inplace=sum(kops.reads_in_place(leaf, block)
-                                       for leaf in leaves))
+                           inplace=inplace)
     return masks, sum(counts), n_bytes, counts
 
 

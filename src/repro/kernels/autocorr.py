@@ -99,8 +99,7 @@ def autocorr_score(x: jnp.ndarray, lags: jnp.ndarray, *,
     auto-detects: compiled on TPU, interpret mode (lowering validation)
     everywhere else.
     """
-    return _autocorr_score(x, lags,
-                           interpret=kb.resolve_interpret("tpu", interpret))
+    return _autocorr_score(x, lags, interpret=kb.resolve_interpret(interpret))
 
 
 def autocorr_score_ref(x: np.ndarray, lags: np.ndarray) -> np.ndarray:

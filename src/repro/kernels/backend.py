@@ -1,21 +1,18 @@
 """Kernel backend detection — the dispatch axis for ``kernels/ops.py``.
 
-Every accelerated op in this repo has up to three lowerings:
+Every accelerated op in this repo has two lowerings:
 
   * ``tpu`` — the Pallas MXU/VPU kernels (``dft.py``, ``autocorr.py``, ...),
     compiled on TPU, interpret-executed elsewhere for validation;
-  * ``gpu`` — the Pallas Triton lowerings (``gpu.py``): plain-Pallas kernel
-    bodies with no TPU-specific memory spaces or scratch, compiled via the
-    Triton backend on GPU, interpret-executed elsewhere;
   * ``xla`` — pure-jnp fallbacks (``ref.py``) that run on any backend.
 
 ``kernel_backend()`` names the lowering the dispatch table should pick for
-the running process; ``force_backend`` overrides it (tests use this to
-exercise the gpu/xla rows of the table on a CPU host). ``resolve_interpret``
-implements the auto-detection contract for the ``interpret=None`` kernel
-default: a kernel compiles only when the *physical* platform matches its
-target — the override never makes Pallas try to compile a Triton kernel on
-a CPU host, it only routes dispatch.
+the running process; ``force_backend`` overrides it (a test hook: tests use
+it to run the Pallas row, in interpret mode, on a CPU host).
+``resolve_interpret`` implements the auto-detection contract for the
+``interpret=None`` kernel default: a kernel compiles only on a physical
+TPU — the override never makes Pallas compile for the TPU on a CPU host,
+it only routes dispatch.
 """
 from __future__ import annotations
 
@@ -25,36 +22,18 @@ from typing import Iterator, Optional
 
 import jax
 
-#: physical jax platforms each kernel target compiles on
-_PLATFORMS = {"tpu": ("tpu",), "gpu": ("gpu", "cuda", "rocm")}
-
 _OVERRIDE: Optional[str] = None
 
 
 def kernel_backend() -> str:
-    """The dispatch-table row for this process: 'tpu', 'gpu' or 'xla'."""
+    """The dispatch-table row for this process: 'tpu' or 'xla'."""
     if _OVERRIDE is not None:
         return _OVERRIDE
-    b = jax.default_backend()
-    if b in _PLATFORMS["tpu"]:
-        return "tpu"
-    if b in _PLATFORMS["gpu"]:
-        return "gpu"
-    return "xla"
+    return "tpu" if jax.default_backend() == "tpu" else "xla"
 
 
 def on_tpu() -> bool:
     return kernel_backend() == "tpu"
-
-
-def on_gpu() -> bool:
-    return kernel_backend() == "gpu"
-
-
-def has_accelerator() -> bool:
-    """True when a compiled kernel lowering (TPU or GPU) is the hot path.
-    The pure-XLA row of the dispatch table serves every other backend."""
-    return kernel_backend() in ("tpu", "gpu")
 
 
 @contextlib.contextmanager
@@ -63,7 +42,7 @@ def force_backend(name: Optional[str]) -> Iterator[None]:
     foreign dispatch row; kernels then run in interpret mode — see
     ``resolve_interpret``). ``None`` restores auto-detection."""
     global _OVERRIDE
-    if name is not None and name not in ("tpu", "gpu", "xla"):
+    if name is not None and name not in ("tpu", "xla"):
         raise ValueError(f"unknown kernel backend {name!r}")
     prev, _OVERRIDE = _OVERRIDE, name
     try:
@@ -84,10 +63,10 @@ def use_compile_cache(default_dir) -> str:
     return str(default_dir)
 
 
-def resolve_interpret(target: str, interpret: Optional[bool]) -> bool:
-    """Auto-detect the ``interpret`` flag for a kernel aimed at ``target``:
-    compiled when the running (physical) platform is the target, interpret
-    mode everywhere else. An explicit True/False always wins."""
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """Auto-detect the ``interpret`` flag of a Pallas kernel: compiled when
+    the running (physical) platform is the TPU, interpret mode everywhere
+    else. An explicit True/False always wins."""
     if interpret is not None:
         return interpret
-    return jax.default_backend() not in _PLATFORMS[target]
+    return jax.default_backend() != "tpu"

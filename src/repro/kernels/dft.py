@@ -173,4 +173,4 @@ def dft_power(x: jnp.ndarray, *, center: bool = False,
     interpret-mode dispatch by default on the platform the kernel targets.
     """
     return _dft_power(x, center=center,
-                      interpret=kb.resolve_interpret("tpu", interpret))
+                      interpret=kb.resolve_interpret(interpret))

@@ -321,7 +321,7 @@ def copy_boxes(new: jnp.ndarray, old: jnp.ndarray, dirty: jnp.ndarray,
     def physical(x):
         return jnp.transpose(x.reshape(shape or (1,)), p.perm)
 
-    interpret = kb.resolve_interpret("tpu", interpret)
+    interpret = kb.resolve_interpret(interpret)
     if p.view is None:
         out = pl.pallas_call(
             _whole_kernel,

@@ -24,9 +24,12 @@ the flat order of the data; a block's max is the max of its partials. With
 - ``slabs``: a block holds whole (second-minor, minor) slabs; ``group``
   slabs fold elementwise, then their rows.
 
-A shape none of these fit (a minor dim or a block that is no multiple of
-128) has no plan; ``max_abs_delta`` serves the caller's ``(n_blocks,
-block)`` view of it, padded to whole lane chunks.
+``dirty_mask`` is the pre-copy's one entry: the dirty mask of any leaf
+pair. A float leaf with a ``plan`` is read in place; a float leaf without
+one (a minor dim or a block that is no multiple of 128) goes into the same
+kernel through a zero-padded ``(n_blocks, block)`` view of its flat data,
+each block padded to whole lane chunks; an integer leaf is compared
+exactly on that view (a float32 cast could alias distinct int32 values).
 """
 from __future__ import annotations
 
@@ -169,8 +172,7 @@ def block_max(new: jnp.ndarray, old: jnp.ndarray, block: int, *,
     (lowering validation) everywhere else.
     """
     p = plan(new.shape, new.dtype, block)
-    q = _partials(new, old, p=p,
-                  interpret=kb.resolve_interpret("tpu", interpret))
+    q = _partials(new, old, p=p, interpret=kb.resolve_interpret(interpret))
     if p.mode == "segments":                  # drop the ragged tile's lanes
         q = q[..., :p.view[-1] // LANES]
     per_block = block // p.per_value
@@ -180,12 +182,44 @@ def block_max(new: jnp.ndarray, old: jnp.ndarray, block: int, *,
     return jnp.max(q.reshape(nb, per_block), axis=1)
 
 
-def max_abs_delta(new: jnp.ndarray, old: jnp.ndarray, *,
-                  interpret=None) -> jnp.ndarray:
-    """(n_blocks, block) x2 -> (n_blocks, 1) f32 max |new - old| per block;
-    a block that is no multiple of 128 is padded with zeros to one."""
-    blk = -(-new.shape[1] // LANES) * LANES
-    if blk != new.shape[1]:
-        pad = ((0, 0), (0, blk - new.shape[1]))
+def reads_in_place(leaf: jnp.ndarray, block: int) -> bool:
+    """Whether ``dirty_mask`` reads ``leaf`` where it lies: a float leaf
+    with a ``plan``."""
+    return (jnp.issubdtype(leaf.dtype, jnp.floating)
+            and plan(leaf.shape, leaf.dtype, block) is not None)
+
+
+def _block_view(leaf: jnp.ndarray, block: int) -> jnp.ndarray:
+    """(any shape) leaf -> (n_blocks, block) zero-padded view of its flat
+    data. Traced inside the jitted scan, so no flattened copy of the whole
+    state is ever materialized at once: that copy would double the
+    state's HBM footprint, beyond one chip for a 4.6 GB replica."""
+    flat = leaf.reshape(-1)
+    nb = -(-flat.shape[0] // block)
+    return jnp.pad(flat, (0, nb * block - flat.shape[0])).reshape(nb, block)
+
+
+def _view_max(new: jnp.ndarray, old: jnp.ndarray,
+              block: int) -> jnp.ndarray:
+    """``block_max`` of a leaf pair without a ``plan``, through its
+    ``_block_view`` with each block padded with zeros to whole lane
+    chunks."""
+    new, old = _block_view(new, block), _block_view(old, block)
+    lanes = -(-block // LANES) * LANES
+    if lanes != block:
+        pad = ((0, 0), (0, lanes - block))
         new, old = jnp.pad(new, pad), jnp.pad(old, pad)
-    return block_max(new, old, blk, interpret=interpret)[:, None]
+    return block_max(new, old, lanes)
+
+
+def dirty_mask(new: jnp.ndarray, old: jnp.ndarray,
+               block: int) -> jnp.ndarray:
+    """Leaf pair (same shape and dtype) -> (n_blocks,) bool mask of the
+    flat blocks of ``block`` elements in which any element differs.
+    Traceable inside a jitted caller."""
+    if reads_in_place(new, block):
+        return block_max(new, old, block) > 0
+    if jnp.issubdtype(new.dtype, jnp.floating):
+        return _view_max(new, old, block) > 0
+    return jnp.any(_block_view(new, block) != _block_view(old, block),
+                   axis=1)

@@ -1,31 +1,32 @@
 """Public wrappers for the accelerated ops — one backend-dispatch table.
 
-Each cycle-recognition op has up to three lowerings, selected per process by
+Each cycle-recognition op has two lowerings, selected per process by
 ``backend.kernel_backend()`` (overridable with ``backend.force_backend`` so
-tests can exercise a foreign row on any host):
+tests can run the Pallas row, in interpret mode, on a CPU host):
 
-  ==================  =======================  ======================  =====================
-  op                  tpu                      gpu                     xla (fallback)
-  ==================  =======================  ======================  =====================
-  power_spectrum      dft.dft_power            gpu.dft_power           ref.dft_power_ref
-                      (Pallas MXU matmul-DFT,  (Pallas Triton,         (jnp complex FFT)
-                      fused mean removal)      dot per weight tile)
-  autocorr_score      autocorr.autocorr_score  gpu.autocorr_score      ref.autocorr_score_
-                      (VMEM rows, lane-roll    (plain-Pallas body)     ref_xla (vmap slices)
+  ==================  ===========================  ============================
+  op                  tpu                          xla (fallback)
+  ==================  ===========================  ============================
+  power_spectrum      dft.dft_power                ref.dft_power_ref
+                      (Pallas MXU matmul-DFT,      (jnp complex FFT)
+                      fused mean removal)
+  autocorr_score      autocorr.autocorr_score      ref.autocorr_score_ref_xla
+                      (VMEM rows, lane-roll        (vmap slices)
                       per prefetched lag)
-  ==================  =======================  ======================  =====================
+  ==================  ===========================  ============================
 
-Pallas rows auto-detect ``interpret``: compiled on their physical target
-platform, interpret mode elsewhere (validation). Shapes outside a kernel's
-tiling contract always fall back to the xla row, so callers never care.
+The Pallas row auto-detects ``interpret``: compiled on a TPU, interpret mode
+elsewhere (validation). Shapes outside a kernel's tiling contract always
+fall back to the xla row, so callers never care.
 
 Both table ops accept an optional ``mesh``: rows are then partitioned across
 the mesh devices with ``shard_map`` (every lowering is embarrassingly
 parallel per row, so sharded results are bit-identical to unsharded) — the
 kernel half of the sharded surveillance plane (``core/shard.py``).
 
-The training-side kernels (flash attention, ssm scan, dirty blocks) keep
-their TPU-or-reference dispatch: they are not on the decide-plane hot path.
+The training-side kernels (flash attention, ssm scan) keep their
+TPU-or-reference dispatch: they are not on the decide-plane hot path. The
+pre-copy calls its kernels' own modules (``dirty_delta``, ``dirty_copy``).
 """
 from __future__ import annotations
 
@@ -37,18 +38,16 @@ import jax.numpy as jnp
 from repro.kernels import autocorr as _ac
 from repro.kernels import backend as kb
 from repro.kernels import dft as _dft
-from repro.kernels import dirty_delta as _dd
 from repro.kernels import flash_attention as _fa
-from repro.kernels import gpu as _gpu
 from repro.kernels import ref
 from repro.kernels import ssm_scan as _ssm
 from repro.kernels.backend import (  # noqa: F401  (re-exported API)
-    force_backend, has_accelerator, kernel_backend, on_gpu, on_tpu)
+    force_backend, kernel_backend, on_tpu)
 
 
 def _interpret() -> bool:
     """Interpret flag for the TPU-only training kernels."""
-    return kb.resolve_interpret("tpu", None)
+    return kb.resolve_interpret(None)
 
 
 def _row_sharded(fn, mesh, x: jnp.ndarray) -> jnp.ndarray:
@@ -69,46 +68,10 @@ def _row_sharded(fn, mesh, x: jnp.ndarray) -> jnp.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# dirty blocks (pre-copy)
-# ---------------------------------------------------------------------------
-def dirty_blocks(new: jnp.ndarray, old: jnp.ndarray,
-                 threshold: float = 0.0) -> jnp.ndarray:
-    """(n_blocks, block) x2 -> (n_blocks,) bool dirty mask.
-
-    Float dtypes go through the Pallas max-|delta| kernel; integer dtypes use
-    an exact != reduction (f32 casting could alias distinct int32 values).
-    """
-    if not jnp.issubdtype(new.dtype, jnp.floating):
-        return jnp.any(new != old, axis=1)
-    d = _dd.max_abs_delta(new, old)
-    return d[:, 0] > threshold
-
-
-def reads_in_place(leaf: jnp.ndarray, block: int) -> bool:
-    """Whether ``leaf_dirty_blocks`` can scan ``leaf``: a float leaf whose
-    shape and ``block`` the kernel reads through a bitcast view
-    (``dirty_delta.plan``)."""
-    return (jnp.issubdtype(leaf.dtype, jnp.floating)
-            and _dd.plan(leaf.shape, leaf.dtype, block) is not None)
-
-
-def leaf_dirty_blocks(new: jnp.ndarray, old: jnp.ndarray,
-                      block: int) -> jnp.ndarray:
-    """Leaf pair (any shape, ``reads_in_place``) -> (n_blocks,) bool dirty
-    mask over its flat blocks of ``block`` elements, the leaf read where
-    it lies: the same mask as ``dirty_blocks`` of the padded block view."""
-    return _dd.block_max(new, old, block) > 0
-
-
-# ---------------------------------------------------------------------------
 # DFT power spectrum (cycle recognition)
 # ---------------------------------------------------------------------------
 def _power_tpu(x: jnp.ndarray, *, center: bool) -> jnp.ndarray:
     return _dft.dft_power(x.astype(jnp.float32), center=center)
-
-
-def _power_gpu(x: jnp.ndarray, *, center: bool) -> jnp.ndarray:
-    return _gpu.dft_power(x.astype(jnp.float32), center=center)
 
 
 def _power_xla(x: jnp.ndarray, *, center: bool) -> jnp.ndarray:
@@ -117,7 +80,7 @@ def _power_xla(x: jnp.ndarray, *, center: bool) -> jnp.ndarray:
     return ref.dft_power_ref(x)
 
 
-POWER_SPECTRUM = {"tpu": _power_tpu, "gpu": _power_gpu, "xla": _power_xla}
+POWER_SPECTRUM = {"tpu": _power_tpu, "xla": _power_xla}
 
 
 def dft_supported(n: int) -> bool:
@@ -145,16 +108,11 @@ def _autocorr_tpu(x, lags):
     return _ac.autocorr_score(x, lags)
 
 
-def _autocorr_gpu(x, lags):
-    return _gpu.autocorr_score(x, lags)
-
-
 def _autocorr_xla(x, lags):
     return ref.autocorr_score_ref_xla(x, lags)
 
 
-AUTOCORR_SCORE = {"tpu": _autocorr_tpu, "gpu": _autocorr_gpu,
-                  "xla": _autocorr_xla}
+AUTOCORR_SCORE = {"tpu": _autocorr_tpu, "xla": _autocorr_xla}
 
 
 def autocorr_supported(n: int) -> bool:
@@ -165,7 +123,7 @@ def autocorr_score(x: jnp.ndarray, lags: jnp.ndarray, *,
                    mesh=None) -> jnp.ndarray:
     """(J, N) rows x (L,) shared candidate lags -> (J, L) scores.
 
-    Pallas kernels on their target accelerators, jnp fallback elsewhere.
+    The Pallas kernel on a TPU, the jnp fallback elsewhere.
     Note the decide plane's CPU hot path does not come through here at all
     — off-accelerator ``cycles._refine_period_batch`` uses a Wiener-
     Khinchin pocketfft pass, which beats any per-lag scoring on host.

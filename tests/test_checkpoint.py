@@ -68,6 +68,33 @@ def test_incremental_delta_then_restore(tmp_path):
     _assert_tree_equal(s, r0)
 
 
+@pytest.mark.parametrize("case", ["bf16_nan", "bf16_inf", "int64_high_word"])
+def test_incremental_delta_is_exact(tmp_path, case):
+    """A change beside a NaN or an unchanged inf in its block, and a change
+    only above 2**32 in an int64 leaf, are saved and restored exactly."""
+    w = np.arange(32 * 48, dtype=np.float32).reshape(32, 48)
+    if case == "bf16_nan":
+        w[0, 0] = np.nan                           # in the changed block
+    if case == "bf16_inf":
+        w[0, 0], w[0, 1] = np.inf, -np.inf
+    s = {"w": np.asarray(w, jnp.bfloat16),
+         "step": np.asarray([3, 2**40 + 7], np.int64)}
+    s2 = {k: v.copy() for k, v in s.items()}
+    if case == "int64_high_word":
+        s2["step"][1] += 2**33
+    else:
+        s2["w"][0, 5] += 1
+    inc = IncrementalCheckpointer(str(tmp_path), block_elems=32,
+                                  full_every=100)
+    inc.save(0, s)
+    stats = inc.save(1, s2)
+    assert stats["kind"] == "delta" and stats["bytes"] > 0
+    with jax.enable_x64(True):                     # JAX holds int64 only so
+        r = inc.restore(1, s)
+    for k in s:
+        assert np.array_equal(np.asarray(r[k]), s2[k], equal_nan=True), k
+
+
 def test_trainer_restores_after_failure(tmp_path):
     """Integration: kill the job at a step, trainer resumes from checkpoint
     and reaches the target step with identical final state semantics."""

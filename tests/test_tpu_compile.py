@@ -76,7 +76,7 @@ def compiled_kernels(monkeypatch):
     """Kernels that pick ``interpret`` from the running backend (the CPU
     here) compile for the described chip instead."""
     monkeypatch.setattr(backend, "resolve_interpret",
-                        lambda target, interpret: False)
+                        lambda interpret: False)
 
 
 def _full_leaf_copies(compiled, size):
@@ -91,10 +91,13 @@ def _full_leaf_copies(compiled, size):
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_max_abs_delta_compiles(one_chip, dtype):
-    new = _spec((4096, 16384), dtype, one_chip)
-    compiled = jax.jit(lambda n, o: dirty_delta.max_abs_delta(
-        n, o, interpret=False)).lower(new, new).compile()
+def test_max_abs_delta_compiles(one_chip, compiled_kernels, dtype):
+    """A leaf the kernel cannot read in place (its minor dim, as the
+    hybrid's ``in_proj``, is no multiple of 128) goes into the same kernel
+    through the padded block view."""
+    new = _spec((4, 2048, 8512), dtype, one_chip)
+    assert not dirty_delta.reads_in_place(new, 16384)
+    compiled = precopy._leaf_dirty.lower(new, new, 16384).compile()
     assert _has_kernel(compiled, "max_abs_delta")
 
 
